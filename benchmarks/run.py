@@ -73,4 +73,9 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    if _SRC not in sys.path:
+        sys.path.insert(0, _SRC)
+    from repro import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

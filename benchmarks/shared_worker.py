@@ -73,8 +73,7 @@ def main(argv=None) -> int:
                          "AUTOSAGE_DEVICE_SIG_OVERRIDE for this worker")
     ap.add_argument("--hw-profile", default=None,
                     help="roofline profile for this worker "
-                         "(AUTOSAGE_HW_PROFILE: cpu, cpu_wide, tpu_v5e, "
-                         "tpu_v4)")
+                         "(AUTOSAGE_HW_PROFILE: cpu, cpu_wide, tpu_v5e)")
     ap.add_argument("--no-transfer", action="store_true",
                     help="disable the cross-device transfer tier "
                          "(AUTOSAGE_TRANSFER=0): the cold-start oracle "
@@ -89,6 +88,8 @@ def main(argv=None) -> int:
     if args.no_transfer:
         os.environ["AUTOSAGE_TRANSFER"] = "0"
 
+    import jax
+
     from repro.core import AutoSage, BatchScheduler, ScheduleCache
 
     sage = AutoSage(
@@ -102,6 +103,7 @@ def main(argv=None) -> int:
     if not args.replay:
         bs.finalize()
     print(json.dumps({
+        "platform": jax.devices()[0].platform,
         "stats": bs.stats(),
         "bucket_choices": {
             r["bucket"]: r["choice"] for r in bs.bucket_stats()

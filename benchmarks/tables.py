@@ -672,8 +672,9 @@ def _run_shared_worker(
         cmd += ["--hw-profile", hw_profile]
     if no_transfer:
         cmd.append("--no-transfer")
-    env = {**os.environ}
-    env.setdefault("JAX_PLATFORMS", "cpu")
+    # the fleet is a CPU rehearsal of cache sharing: workers run on the
+    # CPU whatever this process runs on, and say so in their stats
+    env = {**os.environ, "JAX_PLATFORMS": "cpu"}
     # ambient scheduler knobs must not leak into the measured workers:
     # the flags above are the only configuration a worker runs under
     for knob in (

@@ -1,5 +1,6 @@
-"""End-to-end GNN training on a synthetic Reddit-shaped graph — the
-paper's own workload, with AutoSAGE-scheduled aggregation.
+"""End-to-end GNN training on a synthetic OGBN-Products-shaped graph
+(100 input features, 47 classes, as published) — the paper's own
+workload, with AutoSAGE-scheduled aggregation.
 
 Full-graph training (default):
 
@@ -15,7 +16,9 @@ probing per subgraph:
 
 Fleet mode — N subprocess trainers share ONE schedule cache
 (merge-on-flush under a lockfile; each trainer opens buckets warm from
-its peers' probes and re-probes buckets whose observed runtime drifts):
+its peers' probes and re-probes buckets whose observed runtime drifts).
+The fleet is a CPU rehearsal of cache sharing: the workers run on the
+CPU whatever this host has:
 
     PYTHONPATH=src python examples/train_gnn.py --minibatch 1024 \
         --epochs 2 --workers 4 --cache fleet_cache.json
@@ -27,110 +30,59 @@ import subprocess
 import sys
 import time
 
-sys.path.insert(0, "src")
+sys.path.insert(0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..", "src"))
 
 import jax
-import jax.numpy as jnp
-import numpy as np
 
+from repro import compile_cache
 from repro.configs.base import get_config
-from repro.core import AutoSage, BatchScheduler, ScheduleCache
-from repro.models.gnn import init_gnn, sage_forward, sage_minibatch_forward
-from repro.sparse import reddit_like
+from repro.core import AutoSage, ScheduleCache
+from repro.sparse import products_like
 from repro.sparse.csr import TRANSPOSE_STATS
+from repro.train.gnn import make_data, train_full, train_minibatch
+
+N_CLASSES, IN_DIM = 47, 100  # OGBN-Products
 
 
-def make_data(graph, classes, in_dim, seed=0):
-    n = graph.n_rows
-    rng = np.random.default_rng(seed)
-    # synthetic node features + labels with graph-correlated signal
-    feats = rng.standard_normal((n, in_dim)).astype(np.float32)
-    labels = feats[:, 0] * 3 + rng.standard_normal(n) * 0.3
-    labels = np.digitize(
-        labels, np.quantile(labels, np.linspace(0, 1, classes + 1)[1:-1])
-    ).astype(np.int32)
-    return jnp.asarray(feats), jnp.asarray(labels)
-
-
-def train_full(args, cfg, graph, x, y, classes, in_dim):
+def run_full(args, cfg, graph, x, y):
     sage = AutoSage(cache=ScheduleCache(path=None))
-    params = init_gnn(cfg, jax.random.PRNGKey(0), in_dim, classes)
-
-    def loss_fn(p):
-        # fully scheduled step: forward SpMMs AND their backward
-        # (op="spmm_bwd_b" on the memoized transpose) each get their own
-        # decision. All decides + probes run host-side at trace time, so
-        # the jitted step re-probes nothing.
-        logits = sage_forward(p, graph, x, sage=sage)
-        logp = jax.nn.log_softmax(logits)
-        return -jnp.take_along_axis(logp, y[:, None], 1).mean()
-
-    grad_fn = jax.jit(jax.value_and_grad(loss_fn))
-    lr = 0.05
     t0 = time.time()
-    for epoch in range(args.epochs):
-        loss, g = grad_fn(params)
-        params = jax.tree.map(lambda p, gg: p - lr * gg, params, g)
-        if epoch % 5 == 0 or epoch == args.epochs - 1:
-            print(f"epoch {epoch:3d} loss {float(loss):.4f} ({time.time()-t0:.1f}s)")
+
+    def log(step, loss, ms):
+        if step % 5 == 0 or step == args.epochs - 1:
+            print(f"epoch {step:3d} loss {loss:.4f} ({time.time()-t0:.1f}s)")
+
+    run = train_full(cfg, graph, x, y, N_CLASSES, args.epochs, sage, on_step=log)
+    print(f"step trace {run.trace_s:.1f}s, compile {run.compile_s:.1f}s")
     # show what the scheduler picked, fwd and bwd
-    d = sage.decide(graph, cfg.d_model, "spmm")
-    print(f"scheduler choice for aggregation at F={cfg.d_model}: {d.choice}")
-    n_bwd = len(sage.cache.keys_for_op("spmm_bwd_b"))
+    for op in ("spmm", "spmm_bwd_b"):
+        for key in sage.cache.keys_for_op(op):
+            print(f"scheduler choice {key}: {sage.cache.get(key)['choice']}")
     print(
-        f"backward decisions cached (op=spmm_bwd_b): {n_bwd}; "
         f"csr transposes built={TRANSPOSE_STATS['built']} "
         f"reused={TRANSPOSE_STATS['hits']}"
     )
 
 
-def train_minibatch(args, cfg, graph, x, y, classes, in_dim):
-    """Sampled-subgraph training: one BatchScheduler serves the whole
-    stream of per-step induced subgraphs (one probe per schedule bucket,
-    provisional baseline until the budget reaches a bucket). Each
-    step's wall time feeds `observe` — a coarse signal (fwd+bwd, not
-    the aggregation kernel alone, so kernel-level drift is diluted by
-    the step's fixed cost); a production trainer would time the
-    scheduled aggregation call itself, as tests/test_drift.py and the
-    shared_smoke drift phase do."""
+def run_minibatch(args, cfg, graph, x, y):
+    print(f"platform: {jax.devices()[0].platform}")
     sage = AutoSage(
         cache=ScheduleCache(path=args.cache or None, shared=args.shared or None),
         probe_iters=2, probe_cap_ms=200, probe_frac=0.25,
     )
-    params = init_gnn(cfg, jax.random.PRNGKey(0), in_dim, classes)
-    rng = np.random.default_rng(1 + args.worker_id)
-    lr, t0 = 0.05, time.time()
-    steps_per_epoch = max(1, graph.n_rows // args.minibatch)
+    t0 = time.time()
 
-    with BatchScheduler(sage, probe_budget_ms=args.probe_budget_ms) as bs:
-        for epoch in range(args.epochs):
-            losses = []
-            for _ in range(steps_per_epoch):
-                rows = np.sort(
-                    rng.choice(graph.n_rows, size=args.minibatch, replace=False)
-                )
-                sub = graph.row_slice(rows)
-                yb = y[jnp.asarray(rows)]
+    def log(epoch, loss, bs):
+        print(
+            f"epoch {epoch:3d} loss {loss:.4f} "
+            f"({time.time()-t0:.1f}s)  stream={bs.stats()}"
+        )
 
-                def loss_fn(p):
-                    logits = sage_minibatch_forward(p, sub, rows, x, sage=bs)
-                    logp = jax.nn.log_softmax(logits)
-                    return -jnp.take_along_axis(logp, yb[:, None], 1).mean()
-
-                t_step = time.perf_counter()
-                loss, g = jax.value_and_grad(loss_fn)(params)
-                jax.block_until_ready(loss)
-                step_ms = (time.perf_counter() - t_step) * 1e3
-                # the step's decides (forward spmm + its scheduled
-                # backward) already bucketed this subgraph; last_bucket
-                # avoids a second feature extraction per step
-                bs.observe(bs.last_bucket, step_ms)
-                params = jax.tree.map(lambda p, gg: p - lr * gg, params, g)
-                losses.append(float(loss))
-            print(
-                f"epoch {epoch:3d} loss {np.mean(losses):.4f} "
-                f"({time.time()-t0:.1f}s)  stream={bs.stats()}"
-            )
+    bs = train_minibatch(
+        cfg, graph, x, y, N_CLASSES, sage, args.minibatch, args.epochs,
+        probe_budget_ms=args.probe_budget_ms, seed=args.worker_id,
+        on_epoch=log,
+    ).scheduler
     s = bs.stats()
     print(
         f"batched decide: {s['decides']} decides -> {s['buckets']} buckets, "
@@ -156,7 +108,10 @@ def train_fleet(args):
     """Spawn --workers subprocess trainers against ONE shared schedule
     cache: each worker re-runs this script in --minibatch mode with
     AUTOSAGE_CACHE_SHARED=1, so bucket probes paid by one worker are
-    opened warm by the rest (merge-on-flush, core/cache.py)."""
+    opened warm by the rest (merge-on-flush, core/cache.py). This process
+    never touches JAX, and the workers run on the CPU, so none of them
+    needs a chip another process holds."""
+    print("fleet workers run on platform: cpu")
     cache = args.cache or "fleet_cache.json"
     procs, stats_paths = [], []
     for w in range(args.workers):
@@ -169,10 +124,8 @@ def train_fleet(args):
             "--probe-budget-ms", str(args.probe_budget_ms),
             "--worker-id", str(w), "--stats-json", stats_path,
         ]
-        env = {**os.environ, "AUTOSAGE_CACHE_SHARED": "1"}
-        # a worker that inherits no backend must not probe accelerator
-        # metadata (minutes of hang on cloud hosts); parent's choice wins
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        env = {**os.environ, "AUTOSAGE_CACHE_SHARED": "1",
+               "JAX_PLATFORMS": "cpu"}
         procs.append(subprocess.Popen(cmd, env=env))
     rcs = [p.wait() for p in procs]
     if any(rcs):
@@ -251,15 +204,15 @@ def main():
         finish_obs(args)
         return
 
+    compile_cache.enable()
     cfg = get_config("gnn_sage")
-    graph = reddit_like(scale=args.scale)
-    classes, in_dim = 16, 64
-    x, y = make_data(graph, classes, in_dim)
+    graph = products_like(scale=args.scale)
+    x, y = make_data(graph, N_CLASSES, IN_DIM)
 
     if args.minibatch:
-        train_minibatch(args, cfg, graph, x, y, classes, in_dim)
+        run_minibatch(args, cfg, graph, x, y)
     else:
-        train_full(args, cfg, graph, x, y, classes, in_dim)
+        run_full(args, cfg, graph, x, y)
     finish_obs(args)
 
 

@@ -273,7 +273,8 @@ class BatchScheduler:
     def last_source(self) -> Optional[str]:
         """Tier label the calling thread's last decide() served from:
         "bucket-cache" / "transfer" / "transfer-pending" / "probe" /
-        "drift-pending" / "provisional"."""
+        "drift-pending" / "provisional" / "gated" (the bucket's pinned
+        variant exceeds this graph's on-chip gates; the baseline served)."""
         return getattr(self._decide_tls, "source", None)
 
     @property
@@ -356,7 +357,22 @@ class BatchScheduler:
             self._decide_tls.inline_probes = inline_probes
             with self._lock:
                 d = st.current()
-                if st.probed and st.decision is not None and st.decision.from_cache:
+                # buckets bin shapes coarsely: the variant pinned by the
+                # graph that opened the bucket may exceed the on-chip gates
+                # (SMEM, VMEM, HBM) for this one. It gets the baseline.
+                gated = (
+                    d.variant is not None and not d.variant.is_baseline
+                    and not d.variant.applicable(feat, self.sage.hw)
+                )
+                if gated:
+                    d = Decision(
+                        op=feat.op, choice="baseline", variant=st.base,
+                        guardrail=None, from_cache=False, probe_ms={},
+                        probe_overhead_ms=0.0, probe_iter_ms=0.0,
+                        estimates_ms={},
+                    )
+                    source = "gated"
+                elif st.probed and st.decision is not None and st.decision.from_cache:
                     source = "bucket-cache"
                 elif (
                     st.probed and st.decision is not None

@@ -134,6 +134,27 @@ def _row_serial_penalty(
     return weight * excess * step_t
 
 
+def _hub_anchors(feat: InputFeatures):
+    """(degree, fraction of rows above it) at the stored quantiles."""
+    return (
+        (max(feat.deg_p50, 1.0), 0.50),
+        (max(feat.deg_p90, 1.0), 0.10),
+        (max(feat.deg_p99, 1.0), 0.01),
+        (max(feat.deg_max, 1.0), 0.0),
+    )
+
+
+def hub_rows_bound(feat: InputFeatures, hub_t: float) -> float:
+    """Most rows of degree above ``hub_t``: at most the row fraction of
+    the highest quantile anchor ``hub_t`` clears (the deg_max anchor
+    left out, so one row always counts), and at most nnz / hub_t. The
+    registry's memory gates size hub-split gathers with it; the
+    estimate uses the interpolated ``_hub_row_frac`` instead."""
+    frac = min((f0 for d0, f0 in _hub_anchors(feat)[:3] if hub_t >= d0),
+               default=1.0)
+    return min(feat.n_rows * frac + 1, feat.nnz / max(hub_t, 1))
+
+
 def _hub_row_frac(feat: InputFeatures, hub_t: float) -> float:
     """Fraction of rows whose degree exceeds ``hub_t``, reconstructed
     from the stored degree quantiles by log-degree interpolation between
@@ -146,12 +167,7 @@ def _hub_row_frac(feat: InputFeatures, hub_t: float) -> float:
     anchor fraction; below p50 clamps to 0.5 — past that the 'hub'
     partition is most of the graph and the split is pointless anyway.
     """
-    anchors = (
-        (max(feat.deg_p50, 1.0), 0.50),
-        (max(feat.deg_p90, 1.0), 0.10),
-        (max(feat.deg_p99, 1.0), 0.01),
-        (max(feat.deg_max, 1.0), 0.0),
-    )
+    anchors = _hub_anchors(feat)
     t = max(float(hub_t), 1.0)
     if t < anchors[0][0]:
         return 0.5

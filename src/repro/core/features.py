@@ -18,27 +18,29 @@ from repro.sparse.csr import CSR, graph_signature
 
 @dataclasses.dataclass(frozen=True)
 class HardwareSpec:
-    """Device capability summary. TPU v5e numbers are the dry-run/roofline
-    target (197 bf16 TFLOP/s, 819 GB/s HBM, ~50 GB/s/link ICI); the CPU
-    entry is a rough calibration for native probes in this container."""
+    """Device capability summary: the roofline peaks the estimate ranks
+    with, and the on-chip memory sizes the kernel gates
+    (core/registry.py) check prefetch tables and resident panels
+    against."""
 
     name: str
     peak_flops: float  # FLOP/s
     hbm_bw: float  # bytes/s
     link_bw: float  # bytes/s per ICI link
-    vmem_bytes: int = 16 * 2**20
+    vmem_bytes: int = 16 * 2**20  # scoped VMEM a Pallas kernel may fill
+    hbm_bytes: int = 16 * 10**9
+    smem_bytes: int = 2**20  # scalar memory holding prefetched tables
+    vmem_total_bytes: int = 128 * 2**20
+    source: str = ""
 
     @staticmethod
     def tpu_v5e() -> "HardwareSpec":
-        return HardwareSpec("tpu_v5e", 197e12, 819e9, 50e9)
-
-    @staticmethod
-    def tpu_v4() -> "HardwareSpec":
-        return HardwareSpec("tpu_v4", 275e12, 1228e9, 45e9)
+        return TPU_BY_DEVICE_KIND["TPU v5 lite"]
 
     @staticmethod
     def cpu() -> "HardwareSpec":
-        return HardwareSpec("cpu", 5e10, 2e10, 1e9, vmem_bytes=32 * 2**20)
+        return HardwareSpec("cpu", 5e10, 2e10, 1e9, vmem_bytes=32 * 2**20,
+                            source="rough calibration of interpret-mode probes")
 
     @staticmethod
     def cpu_wide() -> "HardwareSpec":
@@ -47,13 +49,13 @@ class HardwareSpec:
         than on `cpu`. Exists so the cross-device transfer path can be
         exercised — and CI-gated — on a single physical machine by
         pairing it with an AUTOSAGE_DEVICE_SIG_OVERRIDE."""
-        return HardwareSpec("cpu_wide", 5e10, 8e10, 1e9, vmem_bytes=32 * 2**20)
+        return HardwareSpec("cpu_wide", 5e10, 8e10, 1e9, vmem_bytes=32 * 2**20,
+                            source="cpu with 4x the bandwidth")
 
     @staticmethod
     def from_profile(name: str) -> "HardwareSpec":
         profiles: Dict[str, HardwareSpec] = {
             "tpu_v5e": HardwareSpec.tpu_v5e(),
-            "tpu_v4": HardwareSpec.tpu_v4(),
             "cpu": HardwareSpec.cpu(),
             "cpu_wide": HardwareSpec.cpu_wide(),
         }
@@ -65,6 +67,22 @@ class HardwareSpec:
             ) from None
 
     @staticmethod
+    def for_device(device) -> "HardwareSpec":
+        """Profile of one JAX device. A TPU is looked up by its
+        ``device_kind``; a kind missing from TPU_BY_DEVICE_KIND is an
+        error, never another chip's numbers."""
+        if device.platform != "tpu":
+            return HardwareSpec.cpu()
+        kind = getattr(device, "device_kind", "?")
+        try:
+            return TPU_BY_DEVICE_KIND[kind]
+        except KeyError:
+            raise KeyError(
+                f"no hardware profile for TPU device_kind {kind!r}; "
+                f"known: {sorted(TPU_BY_DEVICE_KIND)}"
+            ) from None
+
+    @staticmethod
     def current() -> "HardwareSpec":
         """Roofline profile of this process. AUTOSAGE_HW_PROFILE pins a
         named profile regardless of the physical backend (used together
@@ -73,8 +91,24 @@ class HardwareSpec:
         override = os.environ.get("AUTOSAGE_HW_PROFILE")
         if override:
             return HardwareSpec.from_profile(override)
-        plat = jax.devices()[0].platform
-        return HardwareSpec.tpu_v5e() if plat == "tpu" else HardwareSpec.cpu()
+        return HardwareSpec.for_device(jax.devices()[0])
+
+
+# TPU profiles keyed by jax's ``device_kind``. Peaks are published
+# figures; the SMEM and scoped-VMEM sizes are what the XLA:TPU compiler
+# reports when a kernel overflows them, and the total VMEM is the most
+# a resident Pallas output block was admitted to take (scoped VMEM on
+# top), both found by compiling for a described chip.
+TPU_BY_DEVICE_KIND: Dict[str, HardwareSpec] = {
+    "TPU v5 lite": HardwareSpec(
+        "tpu_v5e", peak_flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        vmem_bytes=16 * 2**20, hbm_bytes=16 * 10**9, smem_bytes=2**20,
+        vmem_total_bytes=128 * 2**20,
+        source="peaks: Google Cloud TPU v5e docs (197 bf16 TFLOP/s, "
+               "819 GB/s, 16 GB HBM, 1,600 Gbit/s ICI); SMEM 1 MiB and "
+               "scoped VMEM 16 MiB: XLA:TPU compile errors",
+    ),
+}
 
 
 def device_sig() -> str:
@@ -231,6 +265,24 @@ class InputFeatures:
             self.dense_tiles_est() * (1.0 - self.padding_waste),
             float(self.n_row_blocks8()),
         )
+
+    def block_ell_bounds(self, rb: int, bc: int) -> tuple:
+        """Upper bounds on (n_row_blocks, W, n_slots) of the (rb, bc)
+        block-ELL layout, from features alone, so the kernel gates run
+        before any conversion. The rb=bc=8 estimates already bound the
+        8x8 layout from above (a block's slot count is at most its summed
+        degree); a taller block merges rb/8 of them, so W grows by at
+        most that factor and the slot count does not grow; wider column
+        blocks only merge slots."""
+        nrb = -(-self.n_rows // rb)
+        ncb = max(1, -(-self.n_cols // bc))
+        if self.ell_width_est > 0:
+            w = -(-rb // 8) * self.ell_width_est
+            slots = math.ceil(self.ragged_tiles_est())
+        else:  # hand-built features: bound from degrees
+            w = rb * max(self.deg_max, 1.0)
+            slots = self.nnz + nrb
+        return nrb, int(min(w, ncb)), int(min(slots, nrb * ncb))
 
     # ---- device-neutral serialization (cache schema v5) --------------
     def to_neutral(self) -> Dict[str, object]:

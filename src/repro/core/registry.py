@@ -29,6 +29,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core.estimate import hub_rows_bound
 from repro.core.features import (
     HardwareSpec,
     InputFeatures,
@@ -123,12 +124,23 @@ def kx_ell_to_csr(ell_vals: jax.Array, aux: Dict) -> jax.Array:
     return ell_vals[rows, slot]
 
 
-def _ell_applicable(f: InputFeatures) -> bool:
+def _gather_fits(rows_x_width: float, f: InputFeatures, hw: HardwareSpec) -> bool:
+    """The XLA row-ELL kernels gather a (rows, width, F) f32 operand and
+    XLA materializes it in HBM (a compile for a described v5e refused
+    row_ell and hub_split_ell on a 122k-node Products-shaped graph at
+    F=256 for 21.6 GB and 44.6 GB). A training step keeps a forward and
+    a backward op's buffers plus activations live, so one gather may
+    take at most a quarter of HBM."""
+    return rows_x_width * f.f * 4 <= hw.hbm_bytes // 4
+
+
+def _ell_applicable(f: InputFeatures, hw: HardwareSpec) -> bool:
     """Uniform-padding gates shared by every row-ELL variant (spmm, sddmm,
     and the attention pipelines): padding explodes under skew, and the
-    padded table must fit host/device memory."""
+    padded table and its gathered operand must fit device memory."""
     return (f.deg_max <= max(32.0, 8 * max(f.avg_deg, 1.0))
-            and f.n_rows * f.deg_max <= 512_000_000)
+            and f.n_rows * f.deg_max <= 512_000_000
+            and _gather_fits(f.n_rows * f.deg_max, f, hw))
 
 
 # ----------------------------------------------------------------- SpMM
@@ -158,7 +170,7 @@ def _spmm_variants(feat: InputFeatures) -> List[Variant]:
             op="spmm",
             prepare=kx.prepare_row_ell,
             build=lambda aux: (lambda b, a=_dev(aux): _spmm_ell_jit(a, b)),
-            applicable=lambda f, hw: _ell_applicable(f),
+            applicable=lambda f, hw: _ell_applicable(f, hw),
         ),
     ]
     hub_t = int(os.environ.get("AUTOSAGE_HUB_T", feat.hub_threshold()))
@@ -172,8 +184,12 @@ def _spmm_variants(feat: InputFeatures) -> List[Variant]:
             ),
             # heavy tail: a small set of rows dominates the work (the
             # p99-based skew misses 1%-hub graphs like Table 10's)
-            applicable=lambda f, hw: f.deg_max > 4 * max(f.avg_deg, 1.0)
-            and f.deg_max > 2 * max(f.deg_p50, 1.0),
+            applicable=lambda f, hw, t=hub_t: f.deg_max > 4 * max(f.avg_deg, 1.0)
+            and f.deg_max > 2 * max(f.deg_p50, 1.0)
+            # hub rows gather deg_max slots each, light rows at most t
+            and _gather_fits(
+                f.n_rows * t + hub_rows_bound(f, t) * f.deg_max, f, hw
+            ),
             knobs={"hub_threshold": hub_t},
         )
     )
@@ -198,13 +214,84 @@ def _spmm_hub_ragged_jit(n_rows: int, f_tile: int, interpret: bool,
     return out
 
 
-def _merge_panels_fit(n_rows: int, n_cols: int, hw: HardwareSpec) -> bool:
-    """Merge-path VMEM gate: the kernels hold a whole (rows x f_tile)
-    output panel plus a whole (cols x f_tile) operand panel resident at
-    f32; leave half of VMEM for the streamed value tiles and double
-    buffering."""
-    panel_bytes = (n_rows + 8 + n_cols + 8) * 128 * 4
-    return panel_bytes <= hw.vmem_bytes // 2
+# ------------------------------------------------------- on-chip gates
+# What the TPU compiler refuses, reckoned from shapes before anything is
+# prepared. Each boundary was found by compiling the kernels for a
+# described v5e; tests/test_tpu_compile.py compiles the largest shape
+# each gate admits and checks that the next refused shape is gated out.
+_LANE_BYTES = 128 * 4  # one f32/int32 row of 128 lanes
+# SMEM the compiler keeps for its own scalars next to the tables
+SMEM_RESERVED_BYTES = 1024
+
+
+def smem_table_bytes(shape) -> int:
+    """SMEM bytes of one scalar-prefetched int32 table. The compiler
+    lays a 1-D table of n words out as ceil(n/128) rows of 128 lanes and
+    a 2-D (a, b) table as a rows of b lanes, pads lanes to 128 and rows
+    to 8 (a one-row table stays one row)."""
+    if len(shape) == 1:
+        rows, lanes = -(-shape[0] // 128), 128
+    else:
+        rows, lanes = shape
+    if rows > 1:
+        rows = -(-rows // 8) * 8
+    return rows * -(-lanes // 128) * _LANE_BYTES
+
+
+def prefetch_tables(family: str, nrb: int, w: int, n_slots: int,
+                    tile_slots: int = 8) -> List[tuple]:
+    """Shapes of the tables a kernel family scalar-prefetches:
+    "dense" (block-ELL SpMM/SDDMM, fused attention), "ragged" (ragged
+    SpMM, ragged attention), "ragged_sddmm", "merge" (merge-path SpMM)
+    and "merge_sddmm"."""
+    n_tiles = -(-n_slots // tile_slots)
+    return {
+        "dense": [(nrb, w)],
+        "ragged": [(nrb + 1,), (n_slots,), (n_slots,)],
+        "ragged_sddmm": [(n_slots,), (n_slots,)],
+        "merge": [(nrb + 1,), (n_tiles * tile_slots,), (n_tiles,), (n_tiles,)],
+        "merge_sddmm": [(nrb + 1,), (n_tiles * tile_slots,), (n_tiles,)],
+    }[family]
+
+
+def smem_fits(tables, hw: HardwareSpec) -> bool:
+    return (sum(smem_table_bytes(s) for s in tables) + SMEM_RESERVED_BYTES
+            <= hw.smem_bytes)
+
+
+def _smem_gate(f: InputFeatures, hw: HardwareSpec, family: str, rb: int = 8,
+               bc: int = 8) -> bool:
+    """SMEM gate on the feature-derived upper bounds of the layout."""
+    nrb, w, n_slots = f.block_ell_bounds(rb, bc)
+    return smem_fits(prefetch_tables(family, nrb, w, n_slots), hw)
+
+
+def merge_vmem_fits(n_rows: int, n_cols: int, tile_slots: int,
+                    hw: HardwareSpec, resident_slots: int = 0) -> bool:
+    """Merge-path VMEM gate. The kernels keep a whole (rows x 128) f32
+    output or X panel and a whole (cols x 128) operand panel resident,
+    and the pipeline double-buffers both, as it does the streamed
+    (tile_slots, 8, 8) value tile, whose 8 lanes pad to 128. That must
+    fit scoped VMEM. The SDDMM kernel also keeps its whole output grid of
+    ``resident_slots`` (8, 8) tiles resident, single-buffered, in the
+    VMEM beyond the scoped part."""
+    rows = -(-n_rows // 8) * 8
+    cols = -(-n_cols // 8) * 8
+    scoped = 2 * (rows + cols + tile_slots * 8) * _LANE_BYTES
+    resident = resident_slots * 8 * _LANE_BYTES
+    return (scoped <= hw.vmem_bytes
+            and resident <= hw.vmem_total_bytes - hw.vmem_bytes)
+
+
+def _merge_gate(f: InputFeatures, hw: HardwareSpec, tile_slots: int,
+                sddmm: bool = False) -> bool:
+    nrb, _, n_slots = f.block_ell_bounds(8, 8)
+    tables = prefetch_tables("merge_sddmm" if sddmm else "merge", nrb, 0,
+                             n_slots, tile_slots)
+    resident = -(-n_slots // tile_slots) * tile_slots if sddmm else 0
+    return smem_fits(tables, hw) and merge_vmem_fits(
+        f.n_rows, f.n_cols, tile_slots, hw, resident_slots=resident
+    )
 
 
 def _pad_b(b: jax.Array, pad_rows: int, pad_f: int) -> jax.Array:
@@ -290,15 +377,18 @@ def _pallas_spmm_variants(feat: InputFeatures, interpret: bool) -> List[Variant]
                         op="spmm",
                         prepare=_prep,
                         build=_build,
-                        applicable=lambda f, hw: f.f >= 32,
+                        applicable=lambda f, hw, rb=rb, bc=bc, ragged=ragged: (
+                            f.f >= 32 and _smem_gate(
+                                f, hw, "ragged" if ragged else "dense", rb, bc)
+                        ),
                         knobs={"rb": rb, "bc": bc, "f_tile": f_tile,
                                **({"ragged": True} if ragged else {})},
                     )
                 )
     # merge-path: nnz-balanced slot tiling (sparse/merge.py); whole B
     # column panel + whole output panel stay VMEM-resident, so the
-    # variant is gated on panel fit — outside it, the ragged family and
-    # the resilience fallback chain take over
+    # variant is gated on panel fit — outside it, the ragged family
+    # takes over
     for tile_slots in (8, 16):
         def _prep_merge(csr, tile_slots=tile_slots):
             bell = csr_to_block_ell(csr, rb=8, bc=8)
@@ -342,8 +432,8 @@ def _pallas_spmm_variants(feat: InputFeatures, interpret: bool) -> List[Variant]
                 op="spmm",
                 prepare=_prep_merge,
                 build=_build_merge,
-                applicable=lambda f, hw: f.f >= 32
-                and _merge_panels_fit(f.n_rows, f.n_cols, hw),
+                applicable=lambda f, hw, ts=tile_slots: f.f >= 32
+                and _merge_gate(f, hw, ts),
                 knobs={"rb": 8, "bc": 8, "f_tile": 128,
                        "tile_slots": tile_slots, "ragged": True},
             )
@@ -392,8 +482,10 @@ def _pallas_spmm_variants(feat: InputFeatures, interpret: bool) -> List[Variant]
             op="spmm",
             prepare=_prep_hub_ragged,
             build=_build_hub_ragged,
+            # each partition's tables are bounded by the whole graph's
             applicable=lambda f, hw: f.f >= 32
-            and f.deg_max > 4 * max(f.avg_deg, 1.0),
+            and f.deg_max > 4 * max(f.avg_deg, 1.0)
+            and _smem_gate(f, hw, "ragged"),
             knobs={"rb": 8, "bc": 8, "f_tile": 128, "ragged": True,
                    "hub_threshold": hub_t},
         )
@@ -458,7 +550,7 @@ def _spmm_dyn_variants(feat: InputFeatures) -> List[Variant]:
             build=lambda aux: (
                 lambda vals, b, a=_dev(aux): _spmm_ell_dyn_jit(a, vals, b)
             ),
-            applicable=lambda f, hw: _ell_applicable(f),
+            applicable=lambda f, hw: _ell_applicable(f, hw),
         ),
     ]
 
@@ -526,7 +618,8 @@ def _pallas_spmm_dyn_variants(feat: InputFeatures, interpret: bool) -> List[Vari
                 prepare=_prep,
                 build=_build,
                 applicable=lambda f, hw, rb=rb, bc=bc: f.f >= 32
-                and f.nnz * rb * bc * 4 <= 512_000_000,
+                and f.nnz * rb * bc * 4 <= 512_000_000
+                and _smem_gate(f, hw, "ragged", rb, bc),
                 knobs={"rb": rb, "bc": bc, "f_tile": 128, "ragged": True},
             )
         )
@@ -594,7 +687,7 @@ def _pallas_spmm_dyn_variants(feat: InputFeatures, interpret: bool) -> List[Vari
             build=_build_merge_dyn,
             applicable=lambda f, hw: f.f >= 32
             and f.nnz * 8 * 8 * 4 <= 512_000_000
-            and _merge_panels_fit(f.n_rows, f.n_cols, hw),
+            and _merge_gate(f, hw, 8),
             knobs={"rb": 8, "bc": 8, "f_tile": 128, "tile_slots": 8,
                    "ragged": True},
         )
@@ -627,7 +720,7 @@ def _sddmm_variants(feat: InputFeatures) -> List[Variant]:
             build=lambda aux: (
                 lambda x, y, a=_dev(aux): _sddmm_ell_jit(a, x, y)
             ),
-            applicable=lambda f, hw: _ell_applicable(f),
+            applicable=lambda f, hw: _ell_applicable(f, hw),
         ),
     ]
 
@@ -737,9 +830,12 @@ def _pallas_sddmm_variants(feat: InputFeatures, interpret: bool) -> List[Variant
                         # (nrb, W, rb, bc) table is ~n_rows * W * bc * 4
                         # bytes with W up to deg_max under skew
                         (lambda f, hw, rb=rb, bc=bc: f.f >= 16
-                         and f.nnz * rb * bc * 4 <= 512_000_000) if ragged
-                        else (lambda f, hw, bc=bc: f.f >= 16
-                              and f.n_rows * f.deg_max * bc * 4 <= 512_000_000)
+                         and f.nnz * rb * bc * 4 <= 512_000_000
+                         and _smem_gate(f, hw, "ragged_sddmm", rb, bc))
+                        if ragged
+                        else (lambda f, hw, rb=rb, bc=bc: f.f >= 16
+                              and f.n_rows * f.deg_max * bc * 4 <= 512_000_000
+                              and _smem_gate(f, hw, "dense", rb, bc))
                     ),
                     knobs={"rb": rb, "bc": bc,
                            **({"ragged": True} if ragged else {})},
@@ -805,9 +901,9 @@ def _pallas_sddmm_variants(feat: InputFeatures, interpret: bool) -> List[Variant
                 op="sddmm",
                 prepare=_prep_merge,
                 build=_build_merge,
-                applicable=lambda f, hw: f.f >= 16
+                applicable=lambda f, hw, ts=tile_slots: f.f >= 16
                 and f.nnz * 8 * 8 * 4 <= 512_000_000
-                and _merge_panels_fit(f.n_rows, f.n_cols, hw),
+                and _merge_gate(f, hw, ts, sddmm=True),
                 knobs={"rb": 8, "bc": 8, "tile_slots": tile_slots,
                        "ragged": True},
             )
@@ -929,7 +1025,7 @@ def _attention_variants(feat: InputFeatures, include_pallas: bool,
                     lambda q, k, v, a=_dev(aux): j(a, q, k, v)
                 ),
                 applicable=(
-                    (lambda f, hw: _ell_applicable(f)) if needs_ell
+                    (lambda f, hw: _ell_applicable(f, hw)) if needs_ell
                     else (lambda f, hw: True)
                 ),
                 knobs={"sddmm": s, "spmm": m},
@@ -948,7 +1044,8 @@ def _attention_variants(feat: InputFeatures, include_pallas: bool,
                 # function than the pipeline computes); mask tile memory
                 # grows with n * deg_max under skew
                 applicable=lambda f, hw: not f.dup_edges
-                and f.n_rows * f.deg_max * bc <= 512_000_000,
+                and f.n_rows * f.deg_max * bc <= 512_000_000
+                and _smem_gate(f, hw, "dense", rb, bc),
                 knobs={"rb": rb, "bc": bc},
             )
         )
@@ -962,7 +1059,8 @@ def _attention_variants(feat: InputFeatures, include_pallas: bool,
                 # the mask table scales with actual slots (<= nnz tiles),
                 # not n_rows x deg_max — skew no longer blows up memory
                 applicable=lambda f, hw: not f.dup_edges
-                and f.nnz * rb * bc * 4 <= 512_000_000,
+                and f.nnz * rb * bc * 4 <= 512_000_000
+                and _smem_gate(f, hw, "ragged", rb, bc),
                 knobs={"rb": rb, "bc": bc, "ragged": True},
             )
         )

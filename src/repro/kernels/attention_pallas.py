@@ -16,7 +16,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import F32_MXU
 
 
 def _fused_attn_kernel(
@@ -34,7 +34,9 @@ def _fused_attn_kernel(
     q = q_ref[...]  # (rb, D)
     k = k_ref[...]  # (bc, D)
     mask = mask_ref[0, 0]  # (rb, bc)
-    logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    logits = jnp.dot(
+        q, k.T, preferred_element_type=jnp.float32, precision=F32_MXU
+    ) * scale
     logits = jnp.where(mask > 0, logits, -jnp.inf)
 
     m_prev = m_scr[:, :1]  # (rb, 1)
@@ -46,7 +48,8 @@ def _fused_attn_kernel(
     alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
     l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-        p, v_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32
+        p, v_ref[...].astype(jnp.float32),
+        preferred_element_type=jnp.float32, precision=F32_MXU,
     )
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -92,7 +95,7 @@ def fused_csr_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((nrb * rb, d), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(colblk, q, k, v, mask)
@@ -114,7 +117,9 @@ def _fused_ragged_attn_kernel(
     q = q_ref[...]  # (rb, D)
     k = k_ref[...]  # (bc, D)
     mask = mask_ref[0]  # (rb, bc)
-    logits = jnp.dot(q, k.T, preferred_element_type=jnp.float32) * scale
+    logits = jnp.dot(
+        q, k.T, preferred_element_type=jnp.float32, precision=F32_MXU
+    ) * scale
     logits = jnp.where(mask > 0, logits, -jnp.inf)
 
     m_prev = m_scr[:, :1]  # (rb, 1)
@@ -127,7 +132,8 @@ def _fused_ragged_attn_kernel(
     alpha = jnp.where(jnp.isfinite(m_prev), jnp.exp(m_prev - m_safe), 0.0)
     l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=1, keepdims=True)
     acc_scr[...] = acc_scr[...] * alpha + jnp.dot(
-        p, v_ref[...].astype(jnp.float32), preferred_element_type=jnp.float32
+        p, v_ref[...].astype(jnp.float32),
+        preferred_element_type=jnp.float32, precision=F32_MXU,
     )
     m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
     l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
@@ -185,7 +191,7 @@ def fused_ragged_attention(
         ),
         out_shape=jax.ShapeDtypeStruct((nrb * rb, d), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary",),
         ),
     )(blkptr, slot_rowblk, slot_colblk, q, k, v, mask)

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import F32_MXU
 from repro.kernels.spmm_pallas import _bisect_rowblk
 
 
@@ -41,7 +41,7 @@ def _sddmm_kernel(colblk_ref, x_ref, y_ref, mask_ref, out_ref, *, n_f_chunks):
     x_tile = x_ref[...]  # (rb, fc)
     y_tile = y_ref[...]  # (bc, fc)
     out_ref[...] += jnp.dot(
-        x_tile, y_tile.T, preferred_element_type=jnp.float32
+        x_tile, y_tile.T, preferred_element_type=jnp.float32, precision=F32_MXU
     )[None, None]
 
     @pl.when(j == n_f_chunks - 1)
@@ -78,7 +78,7 @@ def sddmm_block_ell(
         ),
         out_shape=jax.ShapeDtypeStruct((nrb, w, rb, bc), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(colblk, x, y, mask)
@@ -97,7 +97,7 @@ def _sddmm_ragged_kernel(
     x_tile = x_ref[...]  # (rb, fc)
     y_tile = y_ref[...]  # (bc, fc)
     out_ref[...] += jnp.dot(
-        x_tile, y_tile.T, preferred_element_type=jnp.float32
+        x_tile, y_tile.T, preferred_element_type=jnp.float32, precision=F32_MXU
     )[None]
 
     @pl.when(j == n_f_chunks - 1)
@@ -145,7 +145,7 @@ def sddmm_ragged_ell(
         ),
         out_shape=jax.ShapeDtypeStruct((n_slots, rb, bc), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(slot_rowblk, slot_colblk, x, y, mask)
@@ -183,7 +183,9 @@ def _sddmm_merge_kernel(
         x_blk = x_ref[pl.ds(i * rb, rb), :]  # (rb, fc)
         cb = colblk_ref[s]
         y_blk = y_ref[pl.ds(cb * bc, bc), :]  # (bc, fc)
-        part = jnp.dot(x_blk, y_blk.T, preferred_element_type=jnp.float32)
+        part = jnp.dot(
+            x_blk, y_blk.T, preferred_element_type=jnp.float32, precision=F32_MXU
+        )
         cur = out_ref[pl.ds(t, 1), pl.ds(k, 1)]
         out_ref[pl.ds(t, 1), pl.ds(k, 1)] = cur + part[None, None]
         return carry
@@ -255,7 +257,7 @@ def sddmm_merge_path(
         ),
         out_shape=jax.ShapeDtypeStruct((n_tiles, tile_slots, rb, bc), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
         ),
     )(blkptr, slot_colblk, tile_rowblk, x, y, tile_mask)

@@ -36,7 +36,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.pallas_compat import CompilerParams
+from repro.kernels import F32_MXU
 
 
 def _bisect_rowblk(blkptr_ref, s, lo0, hi0, n_iter):
@@ -71,7 +71,8 @@ def _spmm_kernel(colblk_ref, vals_ref, b_ref, out_ref):
     a_tile = vals_ref[0, 0]  # (rb, bc) f32
     b_tile = b_ref[...]  # (bc, f_tile)
     out_ref[...] += jnp.dot(
-        a_tile, b_tile.astype(a_tile.dtype), preferred_element_type=jnp.float32
+        a_tile, b_tile.astype(a_tile.dtype),
+        preferred_element_type=jnp.float32, precision=F32_MXU,
     )
 
 
@@ -102,7 +103,7 @@ def spmm_block_ell(
         ),
         out_shape=jax.ShapeDtypeStruct((nrb * rb, f), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
     )(colblk, vals, b)
@@ -120,7 +121,8 @@ def _spmm_ragged_kernel(blkptr_ref, rowblk_ref, colblk_ref, vals_ref, b_ref, out
     a_tile = vals_ref[0]  # (rb, bc) f32
     b_tile = b_ref[...]  # (bc, f_tile)
     out_ref[...] += jnp.dot(
-        a_tile, b_tile.astype(a_tile.dtype), preferred_element_type=jnp.float32
+        a_tile, b_tile.astype(a_tile.dtype),
+        preferred_element_type=jnp.float32, precision=F32_MXU,
     )
 
 
@@ -168,7 +170,7 @@ def spmm_ragged_ell(
         ),
         out_shape=jax.ShapeDtypeStruct((nrb * rb, f), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(blkptr, slot_rowblk, slot_colblk, slot_vals, b)
@@ -207,7 +209,8 @@ def _spmm_merge_kernel(
         b_blk = b_ref[pl.ds(cb * bc, bc), :]  # (bc, f_tile)
         cur = out_ref[pl.ds(i * rb, rb), :]
         upd = cur + jnp.dot(
-            a_tile, b_blk.astype(a_tile.dtype), preferred_element_type=jnp.float32
+            a_tile, b_blk.astype(a_tile.dtype),
+            preferred_element_type=jnp.float32, precision=F32_MXU,
         )
         # tail-padded slots of the last tile leave the row untouched
         out_ref[pl.ds(i * rb, rb), :] = jnp.where(k < n_live, upd, cur)
@@ -273,7 +276,7 @@ def spmm_merge_path(
         ),
         out_shape=jax.ShapeDtypeStruct((nrb * rb, f), jnp.float32),
         interpret=interpret,
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
     )(blkptr, slot_colblk, tile_rowblk, tile_nslots, tile_vals, b)

@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import ref
+from repro.kernels import F32_MXU, ref
 from repro.sparse.csr import CSR
 
 
@@ -38,7 +38,7 @@ def prepare_dense(csr: CSR) -> Dict[str, np.ndarray]:
 
 def spmm_dense(aux: Dict, b: jax.Array) -> jax.Array:
     """Densified matmul — wins only for tiny/dense A; estimate gates it."""
-    return aux["a"] @ b.astype(aux["a"].dtype)
+    return jnp.matmul(aux["a"], b.astype(aux["a"].dtype), precision=F32_MXU)
 
 
 def prepare_row_ell(csr: CSR, k: int | None = None) -> Dict[str, np.ndarray]:
@@ -71,7 +71,8 @@ def spmm_row_ell(aux: Dict, b: jax.Array) -> jax.Array:
     variance is low (no tail padding); the 'warp-per-row, feature-tiled'
     analogue."""
     gathered = b[aux["colind"]]  # (n, K, F)
-    return jnp.einsum("nk,nkf->nf", aux["val"], gathered.astype(aux["val"].dtype))
+    return jnp.einsum("nk,nkf->nf", aux["val"], gathered.astype(aux["val"].dtype),
+                      precision=F32_MXU)
 
 
 def prepare_hub_split_ell(csr: CSR, hub_threshold: int) -> Dict[str, np.ndarray]:
@@ -122,7 +123,8 @@ def sddmm_row_ell(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
     layout is required.
     """
     gathered = y[aux["colind"]]  # (n, K, F)
-    out = jnp.einsum("nf,nkf->nk", x.astype(gathered.dtype), gathered)
+    out = jnp.einsum("nf,nkf->nk", x.astype(gathered.dtype), gathered,
+                     precision=F32_MXU)
     return out * (aux["val"] != 0)
 
 
@@ -175,9 +177,11 @@ def attention_ell(aux: Dict, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Ar
     colind = aux["colind"]  # (n, K)
     mask = aux["val"] != 0
     gathered_k = k[colind]  # (n, K, F)
-    logits = jnp.einsum("nf,nkf->nk", q.astype(gathered_k.dtype), gathered_k) * scale
+    logits = jnp.einsum("nf,nkf->nk", q.astype(gathered_k.dtype), gathered_k,
+                        precision=F32_MXU) * scale
     probs = ell_masked_softmax(logits, mask)
-    return jnp.einsum("nk,nkf->nf", probs, v[colind].astype(probs.dtype))
+    return jnp.einsum("nk,nkf->nf", probs, v[colind].astype(probs.dtype),
+                      precision=F32_MXU)
 
 
 def attention_ell_to_csr(aux: Dict, q, k, v) -> jax.Array:
@@ -186,7 +190,8 @@ def attention_ell_to_csr(aux: Dict, q, k, v) -> jax.Array:
     colind = aux["ell_colind"]
     mask = aux["ell_val"] != 0
     gathered_k = k[colind]
-    logits = jnp.einsum("nf,nkf->nk", q.astype(gathered_k.dtype), gathered_k) * scale
+    logits = jnp.einsum("nf,nkf->nk", q.astype(gathered_k.dtype), gathered_k,
+                        precision=F32_MXU) * scale
     probs = ell_masked_softmax(logits, mask)
     probs_csr = probs[aux["edge_row"], aux["edge_slot"]]
     return ref.spmm_ref(aux["rowptr"], aux["colind"], probs_csr, v)
@@ -201,4 +206,5 @@ def attention_csr_to_ell(aux: Dict, q, k, v) -> jax.Array:
     probs_ell = jnp.zeros(ell_colind.shape, probs.dtype).at[
         aux["edge_row"], aux["edge_slot"]
     ].set(probs)
-    return jnp.einsum("nk,nkf->nf", probs_ell, v[ell_colind].astype(probs.dtype))
+    return jnp.einsum("nk,nkf->nf", probs_ell, v[ell_colind].astype(probs.dtype),
+                      precision=F32_MXU)

@@ -8,17 +8,11 @@ module never touches jax device state. Single pod = 16x16 = 256 chips
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.6; 0.4.x (the offline container) has neither the enum
-    from jax.sharding import AxisType  # nor make_mesh(axis_types=...)
-except ImportError:
-    AxisType = None
+from jax.sharding import AxisType
 
 
 def _auto_mesh(shape, axes) -> jax.sharding.Mesh:
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -92,6 +92,9 @@ class GNNServer:
         # a drift-flagged bucket keeps serving its last pinned decision
         # (guardrail-safe) while the re-probe waits in the background
         "drift-pending": "warm",
+        # the bucket's pinned variant exceeds this graph's on-chip gates:
+        # the baseline serves it, final, with no probe pending
+        "gated": "warm",
         "transfer": "transfer",
         "transfer-pending": "transfer",
         "provisional": "provisional",
@@ -541,4 +544,7 @@ def main(argv=None) -> int:
 
 
 if __name__ == "__main__":
+    from repro import compile_cache
+
+    compile_cache.enable()
     sys.exit(main())

@@ -11,15 +11,6 @@ import jax
 import numpy as np
 import pytest
 
-# every test here builds meshes with explicit axis types; jax 0.4.x
-# (the offline container's pin) predates jax.sharding.AxisType, so gate
-# the module on the API rather than fail with AttributeError / hang the
-# 8-fake-device subprocesses
-pytestmark = pytest.mark.skipif(
-    not hasattr(jax.sharding, "AxisType"),
-    reason="requires jax.sharding.AxisType (jax >= 0.6)",
-)
-
 SRC = str(Path(__file__).resolve().parent.parent / "src")
 
 
