@@ -45,10 +45,16 @@ def _decide(sched, csr: CSR, f: int, op: str):
 def _scheduled(sched, csr: CSR, f: int, op: str, *args):
     """decide + (memoized) prepare + run one scheduled op.
 
-    The obs spans here are host-side: under jit they cover trace time
-    (decide + prepare + dispatch of the traced runner), which is exactly
-    the scheduler-overhead story the flight recorder exists to audit —
-    steady-state device time is the probe/benchmark layer's job.
+    The ``fwd.<op>``/``bwd.<op>`` obs span times the host side: under
+    jit, trace time (decide, probe, prepare and the tracing of the
+    runner), the scheduler overhead the flight recorder audits. The
+    device time of the op is named, not timed, here: the runner is
+    called under `obs.op_scope`, a named scope
+    ``autosage.<fwd|bwd>.<op>.f<F>.<choice>`` (``reference`` for the
+    rescue) that every op it traces carries in the compiled program's
+    ``op_name`` metadata, so a profile's device ops can be summed by
+    direction, op, width and choice. A fallback stage of the chain
+    nests its own scope inside (`resilience.chain_runner`).
     """
     kind = "bwd" if "_bwd" in op else "fwd"
     with obs.span(f"{kind}.{op}", op=op):
@@ -69,9 +75,9 @@ def _scheduled(sched, csr: CSR, f: int, op: str, *args):
             resilience.record_fault("decide", "", op, exc)
             resilience.record_fallback("scheduler", "reference", op)
             runner = resilience.reference_runner(csr, op)
-            with obs.span("run", op=op, choice="reference"):
+            with obs.op_scope(kind, op, f, "reference"):
                 return runner(*args)
-        with obs.span("run", op=op, choice=d.choice):
+        with obs.op_scope(kind, op, f, d.choice):
             return runner(*args)
 
 

@@ -1,13 +1,19 @@
 """Scheduler flight recorder: span tracing, metrics, estimate scorecard.
 
-Three instruments behind one gate (`AUTOSAGE_OBS=1`):
+Three instruments:
 
   spans     nested, context-propagated spans over the decision procedure
             (``decide`` -> ``features``/``estimate``/``shortlist``/
-            ``probe``/``guardrail``/``transfer``/``run``, plus
+            ``probe``/``guardrail``/``transfer``, ``prepare``,
+            ``lower``/``compile`` of a training step, plus
             ``cache.lock_wait``/``cache.merge``, ``drift.reprobe`` and
             the fwd/bwd autodiff op spans) with monotonic durations.
-            Buffered in memory and exported as Chrome/Perfetto
+            Two sinks. The profiler sink is always on: each span is a
+            ``jax.profiler.TraceAnnotation`` named ``autosage.<name>``
+            carrying the span's args, so a profile shows it on the
+            device's clock (one TraceMe check when no profiler session
+            runs). The file sink, behind `AUTOSAGE_OBS=1`, buffers spans
+            in memory and exports them as Chrome/Perfetto
             ``trace_event`` JSON — a whole train step or batched epoch
             opens in ui.perfetto.dev.
   metrics   a process-wide registry of counters, gauges and log-bucketed
@@ -24,29 +30,49 @@ Three instruments behind one gate (`AUTOSAGE_OBS=1`):
             closed-loop measurement of roofline estimate quality that
             the transfer tier's residual calibration depends on.
 
+The span stack lives in a `contextvars.ContextVar`, so a thread that
+runs in a copy of its caller's context (`resilience.run_with_timeout`,
+the probe watchdog) keeps its parent, depth and phase. The phase of a
+moment is the innermost of ``probe``, ``prepare`` or ``compile`` on the
+stack (``other`` when none is open); `listen_compiles` observes every
+XLA backend compile (or persistent-cache retrieval) into
+``autosage_xla_compile_ms{phase}``, and `registry.Variant.timed_prepare`
+labels ``autosage_prepare_ms`` with it.
+
+`op_scope` names one scheduled op in the compiled program: a
+`jax.named_scope` ``autosage.<fwd|bwd>.<op>.f<F>.<choice>`` that lands in
+the ``op_name`` metadata of every op traced under it.
+
 Contract (the replay/fleet invariants the rest of the repo relies on):
 
-  * `AUTOSAGE_OBS` unset  => zero overhead beyond in-memory counter
-    bumps, and NO files are ever created (spans are no-ops).
-  * `AUTOSAGE_REPLAY_ONLY=1` => spans and file output are no-ops even
-    with AUTOSAGE_OBS set, so replay-determinism runs stay bit-exact.
+  * `AUTOSAGE_OBS` unset  => no overhead beyond in-memory counter
+    bumps, the span stack and the profiler sink's check, and NO files
+    are ever created.
+  * `AUTOSAGE_REPLAY_ONLY=1` => the file sink and file output are
+    no-ops even with AUTOSAGE_OBS set, so replay-determinism runs stay
+    bit-exact.
   * every line written to a ``.jsonl`` stream is ONE complete record in
     ONE ``write()`` on an O_APPEND descriptor (PR 4's atomicity rule) —
     N fleet workers interleave whole lines, never partial ones.
 
 This module deliberately imports nothing from the rest of the package
-(sparse/csr.py and core/cache.py sit below it in the import graph).
+(sparse/csr.py and core/cache.py sit below it in the import graph), and
+imports jax only where a scope or listener needs it, or a span finds it
+imported.
 """
 from __future__ import annotations
 
 import atexit
 import bisect
+import contextvars
 import json
 import math
 import os
+import re
+import sys
 import threading
 import time
-from contextlib import contextmanager
+from contextlib import contextmanager, nullcontext
 from pathlib import Path
 from typing import Any, Dict, List, Optional, Tuple
 
@@ -89,7 +115,10 @@ _spans_lock = threading.Lock()  # flush/export/reset only, not the hot path
 _spans_flushed = 0  # prefix of _spans already appended to spans.jsonl
 _spans_dropped = 0
 _active_dir: Optional[Path] = None  # obs dir captured at first record
-_tls = threading.local()
+# names of the open spans, innermost last
+_stack: contextvars.ContextVar[Tuple[str, ...]] = contextvars.ContextVar(
+    "autosage_span_stack", default=()
+)
 # wall-clock anchor: ts_us = anchor_wall + (perf_now - anchor_perf), so
 # the hot path reads only the (cheaper, monotonic) perf counter
 _ANCHOR_WALL_NS = time.time_ns()
@@ -120,37 +149,120 @@ def _render(rec: Tuple) -> Dict[str, Any]:
     return out
 
 
+_annotation = None  # jax.profiler.TraceAnnotation, once imported
+
+
+def _profiler_span(name: str, args: Dict[str, Any]):
+    """The profiler sink: a host span ``autosage.<name>`` in the
+    profiler's trace (a TraceMe check, no more, outside a session)."""
+    global _annotation
+    if _annotation is None:
+        if "jax" not in sys.modules:  # no jax yet, so no profiler session
+            return nullcontext()
+        from jax.profiler import TraceAnnotation
+
+        _annotation = TraceAnnotation
+    return _annotation("autosage." + name, **args)
+
+
 @contextmanager
 def span(name: str, **args: Any):
-    """Record one nested span. No-op (and allocation-free on the fast
-    exit) unless `enabled()`. Context propagates through a thread-local
-    stack, so a span opened inside another records its parent and depth;
-    the Chrome trace nests them by containment."""
-    if not enabled():
-        yield None
-        return
-    stack = getattr(_tls, "stack", None)
-    if stack is None:
-        stack = _tls.stack = []
-    parent = stack[-1] if stack else None
-    depth = len(stack)
-    stack.append(name)
-    t0 = time.perf_counter_ns()
+    """One nested span, in both sinks: always in the profiler's trace,
+    and recorded for the files only when `enabled()`. The stack is a
+    context variable, so a span opened inside another records its parent
+    and depth, in a thread too when the thread runs in a copy of the
+    caller's context; the Chrome trace nests them by containment."""
+    stack = _stack.get()
+    token = _stack.set(stack + (name,))
     try:
-        yield None
+        with _profiler_span(name, args):
+            if not enabled():
+                yield None
+                return
+            t0 = time.perf_counter_ns()
+            try:
+                yield None
+            finally:
+                _record(name, t0, stack, args)
     finally:
-        t1 = time.perf_counter_ns()
-        stack.pop()
-        global _spans_dropped, _active_dir
-        if len(_spans) < _SPAN_CAP:
-            _spans.append(
-                (name, t0, t1, threading.get_ident(), parent, depth,
-                 args or None)
-            )
-            if _active_dir is None:
-                _active_dir = obs_dir()
-        else:
-            _spans_dropped += 1
+        _stack.reset(token)
+
+
+def _record(name: str, t0: int, stack: Tuple[str, ...], args: Dict[str, Any]) -> None:
+    """The file sink: buffer one completed span (``stack`` holds the
+    spans open around it)."""
+    t1 = time.perf_counter_ns()
+    global _spans_dropped, _active_dir
+    if len(_spans) < _SPAN_CAP:
+        _spans.append(
+            (name, t0, t1, threading.get_ident(),
+             stack[-1] if stack else None, len(stack), args or None)
+        )
+        if _active_dir is None:
+            _active_dir = obs_dir()
+    else:
+        _spans_dropped += 1
+
+
+# the spans that name a phase of set-up, for the compile and prepare
+# counters
+PHASES = ("probe", "prepare", "compile")
+
+
+def phase() -> str:
+    """The innermost phase span open in this context, or ``other``."""
+    for name in reversed(_stack.get()):
+        if name in PHASES:
+            return name
+    return "other"
+
+
+# jax.monitoring's event for one XLA backend compile; it spans
+# compile_or_get_cached, so a persistent-cache retrieval is one too
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+XLA_COMPILE_MS = "autosage_xla_compile_ms"
+_listen_lock = threading.Lock()
+_listening = False
+
+
+def _on_duration(event: str, duration_secs: float, **_: Any) -> None:
+    if event == BACKEND_COMPILE_EVENT:
+        REGISTRY.observe(XLA_COMPILE_MS, duration_secs * 1e3, phase=phase())
+
+
+def listen_compiles() -> None:
+    """Observe every XLA backend compile of this process, on whatever
+    thread compiles, into ``autosage_xla_compile_ms{phase}``. Registers
+    one jax.monitoring listener, once (idempotent)."""
+    global _listening
+    with _listen_lock:
+        if _listening:
+            return
+        import jax.monitoring
+
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
+
+_UNSCOPED = re.compile(r"[^A-Za-z0-9_.]+")
+
+
+def op_scope_name(kind: str, op: str, f: int, choice: str) -> str:
+    """``autosage.<kind>.<op>.f<F>.<choice>`` with every run of other
+    characters than ``[A-Za-z0-9_.]`` made one ``_`` (a choice is a
+    variant's full name, ``row_ell[rb=8]``), so the name survives the
+    compiled program's metadata and a trace reducer's patterns."""
+    return _UNSCOPED.sub("_", f"autosage.{kind}.{op}.f{int(f)}.{choice}").rstrip("_")
+
+
+def op_scope(kind: str, op: str, f: int, choice: str):
+    """A `jax.named_scope` naming one scheduled op (`op_scope_name`): the
+    ops traced under it carry the name in their ``op_name`` metadata
+    (``jvp(<scope>)`` forward, ``transpose(jvp(<scope>))`` backward).
+    Metadata only: the compiled program is otherwise the same."""
+    import jax
+
+    return jax.named_scope(op_scope_name(kind, op, f, choice))
 
 
 # --------------------------------------------------------------- metrics
@@ -684,8 +796,7 @@ def reset() -> None:
         _spans_dropped = 0
         _active_dir = None
     REGISTRY.reset()
-    if getattr(_tls, "stack", None):
-        _tls.stack = []
+    _stack.set(())
 
 
 def span_names() -> List[str]:

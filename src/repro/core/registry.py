@@ -61,9 +61,11 @@ class Variant:
 
     def timed_prepare(self, csr: CSR, **kwargs) -> Dict:
         """prepare() with the host-side conversion cost accounted to
-        ``autosage_prepare_ms{op,variant}`` — layout build time is part
-        of the amortized cost story (paper's cache warm-up) and the obs
-        flight recorder charges it per variant family."""
+        ``autosage_prepare_ms{op,variant,phase}`` — layout build time is
+        part of the amortized cost story (paper's cache warm-up) and the
+        obs flight recorder charges it per variant family. ``phase``
+        (`obs.phase`) tells a probe's subgraph prepare (``probe``) from
+        a full-graph one (``prepare``, under `AutoSage.build_runner`)."""
         from repro.core import faultinject, obs
 
         faultinject.fault_point("prepare", name=self.full_name(), op=self.op)
@@ -71,7 +73,7 @@ class Variant:
         aux = self.prepare(csr, **kwargs)
         obs.REGISTRY.observe(
             "autosage_prepare_ms", (time.perf_counter() - t0) * 1e3,
-            op=self.op, variant=self.name,
+            op=self.op, variant=self.name, phase=obs.phase(),
         )
         return aux
 

@@ -39,6 +39,7 @@ overhead A/B and an operational escape hatch).
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import os
 import threading
 import time
@@ -191,10 +192,13 @@ def run_with_timeout(
     """Watchdog: run ``fn`` on a daemon thread and give up after
     ``timeout_s`` with ProbeTimeout. The hung thread is abandoned (it
     holds no locks the caller needs); daemon status keeps it from
-    blocking interpreter exit. ``timeout_s`` None/<=0 runs inline."""
+    blocking interpreter exit. ``timeout_s`` None/<=0 runs inline. The
+    thread runs in a copy of the caller's context, so the obs spans it
+    opens keep their parent and phase."""
     if not timeout_s or timeout_s <= 0:
         return fn()
     box: Dict[str, Any] = {}
+    ctx = contextvars.copy_context()
 
     def _target() -> None:
         try:
@@ -202,7 +206,8 @@ def run_with_timeout(
         except BaseException as exc:  # noqa: BLE001 - relayed below
             box["error"] = exc
 
-    t = threading.Thread(target=_target, daemon=True, name=f"watchdog-{site}")
+    t = threading.Thread(target=ctx.run, args=(_target,), daemon=True,
+                         name=f"watchdog-{site}")
     t.start()
     t.join(timeout_s)
     if t.is_alive():
@@ -512,7 +517,8 @@ def fallback_stages(csr, op: str, choice: str, variant, hw) -> List[Tuple]:
     if choice != "baseline":
 
         def build_choice(args, _v=variant):
-            with jax.ensure_compile_time_eval():
+            with obs.span("prepare", op=op, choice=choice), \
+                    jax.ensure_compile_time_eval():
                 aux = _v.timed_prepare(csr)
                 return _v.build(aux)
 
@@ -524,7 +530,8 @@ def fallback_stages(csr, op: str, choice: str, variant, hw) -> List[Tuple]:
 
         feat = InputFeatures.from_csr(csr, _infer_f(op, args), op)
         base = registry.baseline(feat, hw)
-        with jax.ensure_compile_time_eval():
+        with obs.span("prepare", op=op, choice="baseline"), \
+                jax.ensure_compile_time_eval():
             aux = base.timed_prepare(csr)
             return base.build(aux)
 
@@ -547,14 +554,17 @@ def chain_runner(
     ``is_excluded`` (zero per-call cost) until its TTL half-opens it
     again. Without a breaker the stage IS dropped permanently (nothing
     would bound the re-attempt cost). The terminal stage has no
-    fault_point and no further fallback."""
+    fault_point and no further fallback. A stage after the first runs
+    under its own `obs.op_scope`, nested in the caller's, so ops a
+    fallback traced carry the name of the stage that served."""
 
     state: Dict[str, Any] = {"dead": set(), "runners": {}}
+    kind = "bwd" if "_bwd" in op else "fwd"
 
     def run(*args):
         last_exc: Optional[BaseException] = None
         prev_fault: Optional[str] = None
-        for name, build, injectable in stages:
+        for i, (name, build, injectable) in enumerate(stages):
             if name in state["dead"]:
                 continue
             if (
@@ -567,27 +577,30 @@ def chain_runner(
                 prev_fault = None
             runner = state["runners"].get(name)
             site = "prepare" if runner is None else "run"
+            scope = (obs.op_scope(kind, op, _infer_f(op, args), name) if i
+                     else contextlib.nullcontext())
             try:
-                if runner is None:
+                with scope:
+                    if runner is None:
+                        if injectable:
+                            runner = retry_call(
+                                lambda: build(args), "prepare", name=name, op=op
+                            )
+                        else:
+                            runner = build(args)
+                        state["runners"][name] = runner
                     if injectable:
-                        runner = retry_call(
-                            lambda: build(args), "prepare", name=name, op=op
-                        )
+
+                        def attempt(_r=runner, _n=name):
+                            faultinject.fault_point("run", name=_n, op=op)
+                            return _r(*args)
+
+                        out = retry_call(attempt, "run", name=name, op=op)
                     else:
-                        runner = build(args)
-                    state["runners"][name] = runner
-                if injectable:
-
-                    def attempt(_r=runner, _n=name):
-                        faultinject.fault_point("run", name=_n, op=op)
-                        return _r(*args)
-
-                    out = retry_call(attempt, "run", name=name, op=op)
-                else:
-                    out = runner(*args)
-                if breaker is not None and injectable:
-                    breaker.record_success(name)
-                return out
+                        out = runner(*args)
+                    if breaker is not None and injectable:
+                        breaker.record_success(name)
+                    return out
             except Exception as exc:
                 last_exc = exc
                 if breaker is not None:

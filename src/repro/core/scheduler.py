@@ -193,6 +193,7 @@ class AutoSage:
         # probe / transfer; the blacklist persists through the schedule
         # cache so fleet workers share it
         self.breaker = resilience.CircuitBreaker(cache=self.cache)
+        obs.listen_compiles()  # autosage_xla_compile_ms{phase}
 
     # ------------------------------------------------------------------
     def probe_candidates(
@@ -232,15 +233,16 @@ class AutoSage:
             """Effective cost: slope between the two probe sizes (ms per
             full-graph-equivalent), or plain median in point mode."""
             times = []
-            for sub, args in zip(subs, args_per_sub):
-                aux = v.timed_prepare(sub)
-                run = v.build(aux)
-                res = probe_mod.time_callable(
-                    lambda: run(*args), iters=self.probe_iters,
-                    cap_ms=self.probe_cap_ms, name=v.full_name(),
-                )
-                iter_ms_total[0] += sum(res.times_ms)
-                times.append(res.median_ms)
+            with obs.span("probe.candidate", candidate=v.full_name()):
+                for sub, args in zip(subs, args_per_sub):
+                    aux = v.timed_prepare(sub)
+                    run = v.build(aux)
+                    res = probe_mod.time_callable(
+                        lambda: run(*args), iters=self.probe_iters,
+                        cap_ms=self.probe_cap_ms, name=v.full_name(),
+                    )
+                    iter_ms_total[0] += sum(res.times_ms)
+                    times.append(res.median_ms)
             if len(times) == 2:
                 slope = (times[1] - times[0]) / max(subs[1].n_rows - subs[0].n_rows, 1)
                 if slope > 0:

@@ -21,7 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import ArchConfig
-from repro.core import AutoSage, BatchScheduler
+from repro.core import AutoSage, BatchScheduler, obs
 from repro.models.gnn import init_gnn, sage_forward, sage_minibatch_forward
 from repro.sparse.csr import CSR
 
@@ -47,8 +47,8 @@ def _xent(logits: jax.Array, y: jax.Array) -> jax.Array:
 @dataclasses.dataclass
 class FullRun:
     losses: List[float]
-    trace_s: float  # tracing the step: features, decides, probes, prepares
-    compile_s: float  # XLA compile of the traced step
+    trace_s: float  # tracing the step: features, decides, probes, prepares (obs span "lower")
+    compile_s: float  # XLA compile of the traced step (obs span "compile")
     step_ms: List[float]  # each step, ending in block_until_ready
     compiled: object  # the compiled step (memory_analysis, as_text)
 
@@ -77,9 +77,11 @@ def train_full(
         return loss, jax.tree.map(lambda a, b: a - lr * b, p, g)
 
     t0 = time.perf_counter()
-    lowered = jax.jit(step).lower(params)
+    with obs.span("lower"):
+        lowered = jax.jit(step).lower(params)
     t1 = time.perf_counter()
-    compiled = lowered.compile()
+    with obs.span("compile"):
+        compiled = lowered.compile()
     t2 = time.perf_counter()
     losses, step_ms = [], []
     for i in range(steps):
