@@ -47,7 +47,6 @@ from repro.core.scheduler import (
     default_probe_args,
     entry_with_stats,
 )
-from repro.kernels import ref
 from repro.kernels import xla as kx
 from repro.sparse.csr import CSR
 
@@ -272,7 +271,7 @@ def probe_stage_breakdown(
     m_impl = variant.knobs.get("spmm", "gather_segsum")
     out: Dict[str, float] = {}
 
-    rowptr, colind = jnp.asarray(sub.rowptr), jnp.asarray(sub.colind)
+    csr_aux = registry._dev(kx.prepare_csr(sub))
     ell = (registry._prepare_attn_ell(sub)
            if "row_ell" in (s_impl, m_impl) else None)
     ell_colind = None if ell is None else jnp.asarray(ell["colind"])
@@ -285,8 +284,8 @@ def probe_stage_breakdown(
         )
         softmax_fn = jax.jit(lambda lg: kx.ell_masked_softmax(lg, ell_mask))
     else:
-        sddmm_fn = jax.jit(lambda q, k: ref.sddmm_ref(rowptr, colind, q, k) * scale)
-        softmax_fn = jax.jit(lambda lg: ref.row_softmax_ref(rowptr, colind, lg))
+        sddmm_fn = jax.jit(lambda q, k: kx.sddmm_csr(csr_aux, q, k) * scale)
+        softmax_fn = jax.jit(lambda lg: kx.row_softmax(csr_aux, lg))
     out["sddmm"] = _med(lambda: sddmm_fn(q, k), "sddmm")
     logits = jax.block_until_ready(sddmm_fn(q, k))
     out["softmax"] = _med(lambda: softmax_fn(logits), "softmax")
@@ -308,6 +307,6 @@ def probe_stage_breakdown(
             slots = kx.prepare_edge_slots(sub)
             er, es = jnp.asarray(slots["edge_row"]), jnp.asarray(slots["edge_slot"])
             probs = jax.block_until_ready(probs[er, es])
-        spmm_fn = jax.jit(lambda p, v: ref.spmm_ref(rowptr, colind, p, v))
+        spmm_fn = jax.jit(lambda p, v: kx.spmm_csr(csr_aux, p, v))
     out["spmm"] = _med(lambda: spmm_fn(probs, v), "spmm")
     return out

@@ -36,7 +36,6 @@ from repro.core.features import (
     op_dynamic_vals,
     op_kind,
 )
-from repro.kernels import ref
 from repro.kernels import xla as kx
 from repro.sparse.bsr import block_ell_edge_index, csr_to_block_ell, hub_split
 from repro.sparse.merge import build_merge_path
@@ -112,18 +111,7 @@ def _sddmm_ell_jit(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
     ell = kx.sddmm_row_ell(
         {"colind": aux["ell_colind"], "val": aux["ell_val"]}, x, y
     )
-    return kx_ell_to_csr(ell, aux)
-
-
-def kx_ell_to_csr(ell_vals: jax.Array, aux: Dict) -> jax.Array:
-    rowptr = aux["rowptr"]
-    nnz = aux["colind"].shape[0]
-    rows = (
-        jnp.searchsorted(rowptr, jnp.arange(nnz, dtype=rowptr.dtype), side="right")
-        - 1
-    )
-    slot = jnp.arange(nnz, dtype=rowptr.dtype) - rowptr[rows]
-    return ell_vals[rows, slot]
+    return ell[aux["edge_row"], aux["edge_slot"]]
 
 
 def _gather_fits(rows_x_width: float, f: InputFeatures, hw: HardwareSpec) -> bool:
@@ -505,7 +493,7 @@ def _pallas_spmm_variants(feat: InputFeatures, interpret: bool) -> List[Variant]
 
 @jax.jit
 def _spmm_gather_dyn_jit(aux: Dict, vals: jax.Array, b: jax.Array) -> jax.Array:
-    return ref.spmm_ref(aux["rowptr"], aux["colind"], vals, b)
+    return kx.spmm_csr(aux, vals, b)
 
 
 @jax.jit
@@ -524,6 +512,7 @@ def _prep_csr_structural(csr: CSR) -> Dict[str, np.ndarray]:
     return {
         "rowptr": np.asarray(csr.rowptr, np.int32),
         "colind": np.asarray(csr.colind, np.int32),
+        "edge_row": kx.edge_rows(csr),
     }
 
 
@@ -713,11 +702,11 @@ def _sddmm_variants(feat: InputFeatures) -> List[Variant]:
         Variant(
             name="row_ell",
             op="sddmm",
-            # NOTE: distinct key names — the CSR dict also has 'colind'
-            # (flat nnz), which must not clobber the (n, K) ELL table
+            # the (n, K) ELL table, and each edge's (row, slot) in it to
+            # read the values back in CSR order
             prepare=lambda csr: {
                 **{f"ell_{k}": v for k, v in kx.prepare_row_ell(csr).items()},
-                **kx.prepare_csr(csr),
+                **kx.prepare_edge_slots(csr),
             },
             build=lambda aux: (
                 lambda x, y, a=_dev(aux): _sddmm_ell_jit(a, x, y)

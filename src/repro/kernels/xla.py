@@ -14,22 +14,57 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.kernels import F32_MXU, ref
+from repro.kernels import F32_MXU
 from repro.sparse.csr import CSR
 
 
-# ---------------------------------------------------------------- SpMM
+# ------------------------------------------------------- CSR, row ids
+# The CSR variants read each edge's row from ``edge_row``, made once on
+# the host, where ref.py's oracles binary-search rowptr on every call.
+# Each body below is its oracle's with the search taken out, op for op,
+# so the two agree bit for bit (tests/test_xla_row_ids.py).
+
+def edge_rows(csr: CSR) -> np.ndarray:
+    """Row id of every nnz entry, int32[nnz]."""
+    return np.repeat(np.arange(csr.n_rows), csr.degrees).astype(np.int32)
+
+
 def prepare_csr(csr: CSR) -> Dict[str, np.ndarray]:
     return {
         "rowptr": np.asarray(csr.rowptr, np.int32),
         "colind": np.asarray(csr.colind, np.int32),
         "val": csr.values_or_ones(np.float32),
+        "edge_row": edge_rows(csr),
     }
 
 
+def spmm_csr(aux: Dict, val: jax.Array, b: jax.Array) -> jax.Array:
+    """`ref.spmm_ref` on prepared row ids; ``val`` per edge."""
+    gathered = b[aux["colind"]] * val[:, None].astype(b.dtype)
+    return jax.ops.segment_sum(
+        gathered, aux["edge_row"], num_segments=aux["rowptr"].shape[0] - 1
+    )
+
+
+def sddmm_csr(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
+    """`ref.sddmm_ref` on prepared row ids."""
+    return jnp.sum(x[aux["edge_row"]] * y[aux["colind"]], axis=-1)
+
+
+def row_softmax(aux: Dict, val: jax.Array) -> jax.Array:
+    """`ref.row_softmax_ref` on prepared row ids."""
+    rows, n_rows = aux["edge_row"], aux["rowptr"].shape[0] - 1
+    row_max = jax.ops.segment_max(val, rows, num_segments=n_rows)
+    row_max = jnp.where(jnp.isfinite(row_max), row_max, 0.0)
+    shifted = jnp.exp(val - row_max[rows])
+    denom = jax.ops.segment_sum(shifted, rows, num_segments=n_rows)
+    return shifted / jnp.maximum(denom[rows], 1e-30)
+
+
+# ---------------------------------------------------------------- SpMM
 def spmm_gather_segsum(aux: Dict, b: jax.Array) -> jax.Array:
     """Baseline SpMM: gather + segment-sum (cuSPARSE stand-in)."""
-    return ref.spmm_ref(aux["rowptr"], aux["colind"], aux["val"], b)
+    return spmm_csr(aux, aux["val"], b)
 
 
 def prepare_dense(csr: CSR) -> Dict[str, np.ndarray]:
@@ -113,7 +148,7 @@ def spmm_hub_split_ell(aux: Dict, b: jax.Array) -> jax.Array:
 # --------------------------------------------------------------- SDDMM
 def sddmm_gather_dot(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
     """Paper's SDDMM baseline: gather both sides, dot."""
-    return ref.sddmm_ref(aux["rowptr"], aux["colind"], x, y)
+    return sddmm_csr(aux, x, y)
 
 
 def sddmm_row_ell(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
@@ -128,16 +163,6 @@ def sddmm_row_ell(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
     return out * (aux["val"] != 0)
 
 
-def row_softmax(aux: Dict, val: jax.Array) -> jax.Array:
-    return ref.row_softmax_ref(aux["rowptr"], aux["colind"], val)
-
-
-def csr_attention(
-    aux: Dict, q: jax.Array, k: jax.Array, v: jax.Array, scale=None
-) -> jax.Array:
-    return ref.csr_attention_ref(aux["rowptr"], aux["colind"], q, k, v, scale)
-
-
 # ------------------------------------------- composed attention pipelines
 # The pipeline scheduler (core/pipeline.py) selects among these whole
 # SDDMM -> row-softmax -> SpMM compositions; each stays in one sparse
@@ -146,10 +171,8 @@ def csr_attention(
 def prepare_edge_slots(csr: CSR) -> Dict[str, np.ndarray]:
     """(row, slot-within-row) of every nnz entry — the scatter/gather
     indices that convert per-edge CSR values to/from the (n, K) ELL table."""
-    deg = csr.degrees
-    rows = np.repeat(np.arange(csr.n_rows), deg).astype(np.int32)
-    slot = (np.arange(csr.nnz) - np.repeat(csr.rowptr[:-1], deg)).astype(np.int32)
-    return {"edge_row": rows, "edge_slot": slot}
+    slot = np.arange(csr.nnz) - np.repeat(csr.rowptr[:-1], csr.degrees)
+    return {"edge_row": edge_rows(csr), "edge_slot": slot.astype(np.int32)}
 
 
 def ell_masked_softmax(logits: jax.Array, mask: jax.Array) -> jax.Array:
@@ -165,9 +188,9 @@ def ell_masked_softmax(logits: jax.Array, mask: jax.Array) -> jax.Array:
 def attention_csr(aux: Dict, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
     """gather_dot SDDMM -> CSR softmax -> gather_segsum SpMM (baseline)."""
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    logits = ref.sddmm_ref(aux["rowptr"], aux["colind"], q, k) * scale
-    probs = ref.row_softmax_ref(aux["rowptr"], aux["colind"], logits)
-    return ref.spmm_ref(aux["rowptr"], aux["colind"], probs, v)
+    logits = sddmm_csr(aux, q, k) * scale
+    probs = row_softmax(aux, logits)
+    return spmm_csr(aux, probs, v)
 
 
 def attention_ell(aux: Dict, q: jax.Array, k: jax.Array, v: jax.Array) -> jax.Array:
@@ -194,14 +217,14 @@ def attention_ell_to_csr(aux: Dict, q, k, v) -> jax.Array:
                         precision=F32_MXU) * scale
     probs = ell_masked_softmax(logits, mask)
     probs_csr = probs[aux["edge_row"], aux["edge_slot"]]
-    return ref.spmm_ref(aux["rowptr"], aux["colind"], probs_csr, v)
+    return spmm_csr(aux, probs_csr, v)
 
 
 def attention_csr_to_ell(aux: Dict, q, k, v) -> jax.Array:
     """gather_dot SDDMM/softmax -> (CSR->ELL scatter) -> row_ell SpMM."""
     scale = 1.0 / (q.shape[-1] ** 0.5)
-    logits = ref.sddmm_ref(aux["rowptr"], aux["colind"], q, k) * scale
-    probs = ref.row_softmax_ref(aux["rowptr"], aux["colind"], logits)
+    logits = sddmm_csr(aux, q, k) * scale
+    probs = row_softmax(aux, logits)
     ell_colind = aux["ell_colind"]  # (n, K)
     probs_ell = jnp.zeros(ell_colind.shape, probs.dtype).at[
         aux["edge_row"], aux["edge_slot"]

@@ -39,7 +39,7 @@ from repro.core import AutoSage, BatchScheduler, ScheduleCache
 from repro.core import faultinject, resilience
 from repro.core.cache import ReplayMiss
 from repro.kernels import ref
-from repro.sparse import hub_skew
+from repro.sparse import fixed_degree, hub_skew
 
 OPS = ("spmm", "sddmm", "attention")
 SCHEDULERS = ("autosage", "batch", "batch-shared")
@@ -71,6 +71,19 @@ def _clean_injection(monkeypatch):
 
 def _graph(seed=0):
     return hub_skew(600, 4, 0.05, 24, seed=seed).dedup_edges()
+
+
+# A graph on which a challenger (row_ell) beats the baseline on its
+# merits on a CPU: one (n, K, F) gather and a dense reduce against a
+# gather and a scatter-add, about 1.5x at F=8 on uniform deg-18 rows.
+# Probing the whole graph 50 times resolves that margin; the default
+# slope between two small subgraphs does not.
+CHALLENGER_F = 8
+CHALLENGER_PROBE = dict(probe_iters=50, probe_cap_ms=500, probe_frac=1.0)
+
+
+def _challenger_graph():
+    return fixed_degree(2048, 18, seed=0)
 
 
 def _make_scheduler(kind, tmp_path):
@@ -306,15 +319,14 @@ def test_repeated_run_faults_quarantine_and_serve_reference(
     breaker threshold, lands in the shared cache's blacklist, and later
     schedulers exclude it from the shortlist outright."""
     path = str(tmp_path / "shared.json")
-    csr = _graph()
-    b = jnp.ones((csr.n_cols, 16), jnp.float32)
+    csr = _challenger_graph()
+    b = jnp.ones((csr.n_cols, CHALLENGER_F), jnp.float32)
     monkeypatch.setenv("AUTOSAGE_FAULT", "run::raise:")
     faultinject.reset()
     s1 = AutoSage(
-        cache=ScheduleCache(path=path, shared=True), probe_iters=1,
-        probe_cap_ms=25, probe_frac=0.25,
+        cache=ScheduleCache(path=path, shared=True), **CHALLENGER_PROBE
     )
-    d1 = s1.decide(csr, 16, "spmm")
+    d1 = s1.decide(csr, CHALLENGER_F, "spmm")
     runner = s1.build_runner(csr, d1)
     for _ in range(4):
         runner(b)
@@ -325,8 +337,7 @@ def test_repeated_run_faults_quarantine_and_serve_reference(
     monkeypatch.delenv("AUTOSAGE_FAULT")
     faultinject.reset()
     s2 = AutoSage(
-        cache=ScheduleCache(path=path, shared=True), probe_iters=1,
-        probe_cap_ms=25, probe_frac=0.25,
+        cache=ScheduleCache(path=path, shared=True), **CHALLENGER_PROBE
     )
     d2 = s2.decide(csr, 24, "spmm")  # different F: fresh decision
     assert d2.choice != d1.choice
@@ -335,12 +346,9 @@ def test_repeated_run_faults_quarantine_and_serve_reference(
 # ------------------------------------------------- replay contract
 def test_replay_of_quarantined_pin_raises_replaymiss(tmp_path, monkeypatch):
     path = str(tmp_path / "c.json")
-    csr = _graph()
-    sage = AutoSage(
-        cache=ScheduleCache(path=path), probe_iters=1, probe_cap_ms=25,
-        probe_frac=0.25,
-    )
-    d = sage.decide(csr, 16, "spmm")
+    csr = _challenger_graph()
+    sage = AutoSage(cache=ScheduleCache(path=path), **CHALLENGER_PROBE)
+    d = sage.decide(csr, CHALLENGER_F, "spmm")
     if d.choice == "baseline":
         pytest.skip("baseline pins are never quarantined")
     # quarantine the pinned choice (e.g. a peer blacklisted it)
@@ -350,14 +358,11 @@ def test_replay_of_quarantined_pin_raises_replaymiss(tmp_path, monkeypatch):
 
     replay_sage = AutoSage(cache=ScheduleCache(path=path, replay_only=True))
     with pytest.raises(ReplayMiss, match="quarantined"):
-        replay_sage.decide(csr, 16, "spmm")
+        replay_sage.decide(csr, CHALLENGER_F, "spmm")
     # outside replay the same state re-decides honestly instead
-    fresh = AutoSage(
-        cache=ScheduleCache(path=path), probe_iters=1, probe_cap_ms=25,
-        probe_frac=0.25,
-    )
+    fresh = AutoSage(cache=ScheduleCache(path=path), **CHALLENGER_PROBE)
     fresh.breaker.maybe_sync()
-    d2 = fresh.decide(csr, 16, "spmm")
+    d2 = fresh.decide(csr, CHALLENGER_F, "spmm")
     assert d2.choice != d.choice
 
 
@@ -367,13 +372,10 @@ def test_batch_reopens_bucket_when_pinned_choice_faults(tmp_path, monkeypatch):
     constructible but faults at first run must not serve its fallback
     forever under the pinned name — the breaker signal re-opens the
     bucket and the next pump re-probes honestly."""
-    csr = _graph()
-    sage = AutoSage(
-        cache=ScheduleCache(path=None), probe_iters=1, probe_cap_ms=25,
-        probe_frac=0.25,
-    )
+    csr = _challenger_graph()
+    sage = AutoSage(cache=ScheduleCache(path=None), **CHALLENGER_PROBE)
     bs = BatchScheduler(sage, probe_budget_ms=10_000)
-    b = jnp.ones((csr.n_cols, 16), jnp.float32)
+    b = jnp.ones((csr.n_cols, CHALLENGER_F), jnp.float32)
     out, d = bs.spmm(csr, b)
     if d.choice == "baseline":
         pytest.skip("probe pinned the baseline; no run-fault path")
@@ -477,7 +479,7 @@ import os, sys
 sys.path.insert(0, {src!r})
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 from repro.core import AutoSage, BatchScheduler, ScheduleCache
-from repro.sparse import hub_skew
+from repro.sparse import fixed_degree, hub_skew
 import jax.numpy as jnp
 csr = hub_skew(600, 4, 0.05, 24, seed=0).dedup_edges()
 sage = AutoSage(cache=ScheduleCache(path=sys.argv[1], shared=True),
