@@ -104,12 +104,18 @@ def attention(
 ) -> jax.Array:
     """CSR attention: SDDMM -> row-softmax -> SpMM on S(A).
 
-    The scheduled path makes one joint pipeline-level decision (composed
-    3-kernel candidates vs the fused Pallas kernel) and assumes the
-    default ``scale = 1/sqrt(d)``; a custom ``scale`` routes to the
+    q/k/v are (n, D), one head, or (n, H, D): H heads in one op, each
+    with its own softmax over a row's edges, and an (n, H, D) result.
+    The scheduled path makes one joint pipeline-level decision per
+    (graph, H, D) (composed 3-kernel candidates vs the fused Pallas
+    kernel; only variants with a head axis apply at H > 1) and assumes
+    the default ``scale = 1/sqrt(D)``; a custom ``scale`` routes to the
     reference pipeline (still differentiable) since the fused kernels
     bake the default.
     """
+    if q.ndim == 3 and q.shape[1] == 1:  # one head: the (n, D) op
+        return attention(csr, q[:, 0], k[:, 0], v[:, 0], sage=sage, scale=scale,
+                         differentiable=differentiable)[:, None]
     if sage is None or scale is not None:
         return ref.csr_attention_ref(
             jnp.asarray(csr.rowptr), jnp.asarray(csr.colind), q, k, v, scale
@@ -117,5 +123,6 @@ def attention(
     if differentiable:
         return autodiff.attention(csr, q, k, v, sched=sage)
     return autodiff._scheduled(
-        sage, csr.structural(), q.shape[1], "attention", q, k, v
+        sage, csr.structural(), q.shape[-1], "attention", q, k, v,
+        heads=1 if q.ndim == 2 else q.shape[1],
     )

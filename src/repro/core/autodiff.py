@@ -24,26 +24,28 @@ through it.
 """
 from __future__ import annotations
 
-from typing import Optional
+from typing import Dict, Optional
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import obs
-from repro.kernels import ref
+from repro.kernels import xla as kx
 from repro.sparse.csr import CSR
 
 
-def _decide(sched, csr: CSR, f: int, op: str):
+def _decide(sched, csr: CSR, f: int, op: str, heads: int):
     """One scheduled decision; AutoSage's pipeline-level attention decide
     when available (BatchScheduler buckets attention via generic decide)."""
     if op == "attention" and hasattr(sched, "decide_attention"):
-        return sched.decide_attention(csr, f)
-    return sched.decide(csr, f, op)
+        return sched.decide_attention(csr, f, heads=heads)
+    return sched.decide(csr, f, op, heads=heads)
 
 
-def _scheduled(sched, csr: CSR, f: int, op: str, *args):
-    """decide + (memoized) prepare + run one scheduled op.
+def _scheduled(sched, csr: CSR, f: int, op: str, *args, heads: int = 1):
+    """decide + (memoized) prepare + run one scheduled op; ``f`` is the
+    width per head, and ``heads`` > 1 runs (n, heads, f) operands.
 
     The ``fwd.<op>``/``bwd.<op>`` obs span times the host side: under
     jit, trace time (decide, probe, prepare and the tracing of the
@@ -57,20 +59,22 @@ def _scheduled(sched, csr: CSR, f: int, op: str, *args):
     nests its own scope inside (`resilience.chain_runner`).
     """
     kind = "bwd" if "_bwd" in op else "fwd"
-    with obs.span(f"{kind}.{op}", op=op):
+    with obs.span(f"{kind}.{op}", op=op, heads=heads):
         try:
-            d = _decide(sched, csr, int(f), op)
+            d = _decide(sched, csr, int(f), op, heads)
             runner = sched.build_runner(csr, d)
         except Exception as exc:
             # defense in depth for non-AutoSage scheds (duck-typed custom
             # schedulers have no fallback chain of their own): a training
             # step's bwd op must never die on a scheduling fault. The
             # reference oracle is always runnable. ReplayMiss stays loud
-            # — the replay contract forbids silent substitution.
+            # — the replay contract forbids silent substitution — and so
+            # does a scheduler that cannot decide the op at all.
             from repro.core import resilience
             from repro.core.cache import ReplayMiss
 
-            if isinstance(exc, ReplayMiss) or not resilience.enabled():
+            if (isinstance(exc, (ReplayMiss, NotImplementedError))
+                    or not resilience.enabled()):
                 raise
             resilience.record_fault("decide", "", op, exc)
             resilience.record_fallback("scheduler", "reference", op)
@@ -166,47 +170,78 @@ def sddmm(csr: CSR, x: jax.Array, y: jax.Array, *, sched):
 
 
 # ------------------------------------------------------------ attention
+def _row_ids(s: CSR) -> Dict:
+    """``rowptr`` and the prepared ``edge_row`` of ``s`` for the backward's
+    softmax pieces (`kx.row_softmax`, `kx.row_softmax_bwd`), made once
+    per graph: every layer's backward closes over the same device array."""
+    memo = getattr(s, "_row_ids_memo", None)
+    if memo is None:
+        with jax.ensure_compile_time_eval():
+            memo = {"rowptr": np.asarray(s.rowptr, np.int32),
+                    "edge_row": jnp.asarray(kx.edge_rows(s))}
+        object.__setattr__(s, "_row_ids_memo", memo)
+    return memo
+
+
 def attention(csr: CSR, q: jax.Array, k: jax.Array, v: jax.Array, *, sched):
     """CSR attention (SDDMM -> row-softmax -> SpMM) through the
     pipeline-level scheduler, differentiable.
 
-    The forward is the joint op="attention" decision (fused Pallas kernel
-    or a composed 3-kernel pipeline). There is no fused backward kernel,
-    so the backward decomposes into its sparse pieces, each scheduled in
-    its own right: logits recompute and grad-of-probs are pattern-only
-    SDDMMs ("attention_bwd_e" / "attention_bwd_p"), the q/k/v grads are
-    dynamic-vals SpMMs ("attention_bwd_q"/"_k"/"_v") whose sparse values
-    are the probs / softmax-VJP'd logits; the softmax VJP itself is a
-    cheap segment op. Scale is the pipeline's default 1/sqrt(d).
+    q/k/v are (n, D) for one head or (n, H, D) for H heads computed as
+    one op, with (H, nnz) edge values in every piece and one decision
+    per (graph, H, D). The forward is the joint op="attention" decision
+    (fused Pallas kernel or a composed 3-kernel pipeline). There is no
+    fused backward kernel, so the backward decomposes into its sparse
+    pieces, each scheduled in its own right: logits recompute and
+    grad-of-probs are pattern-only SDDMMs ("attention_bwd_e" /
+    "attention_bwd_p"), the q/k/v grads are dynamic-vals SpMMs
+    ("attention_bwd_q"/"_k"/"_v") whose sparse values are the probs /
+    softmax-VJP'd logits; the softmax and its VJP are segment ops on the
+    prepared row ids, each under its own scope
+    (``autosage.bwd.attention_softmax…``, ``…attention_softmax_bwd…``).
+    Scale is the pipeline's default 1/sqrt(D).
     """
     s = csr.structural()
+    heads = 1 if q.ndim == 2 else int(q.shape[1])
+    d = int(q.shape[-1])
 
     @jax.custom_vjp
     def _f(q, k, v):
-        return _scheduled(sched, s, q.shape[1], "attention", q, k, v)
+        return _scheduled(sched, s, d, "attention", q, k, v, heads=heads)
 
     def _fwd(q, k, v):
         return _f(q, k, v), (q, k, v)
 
     def _bwd(res, g):
-        q_r, k_r, v_r = res
-        scale = 1.0 / (q_r.shape[-1] ** 0.5)
-        rowptr, colind = jnp.asarray(s.rowptr), jnp.asarray(s.colind)
+        # With heads, each piece's gathered (H*D)-wide rows are live only
+        # inside it: the barriers keep the compiler from merging a piece's
+        # gathers with identical ones of the forward or of another piece
+        # (which would hold them from one to the other) and run the pieces
+        # one after another.
+        barrier = jax.lax.optimization_barrier if heads > 1 else (lambda a: a)
+        q_r, k_r, v_r = barrier(res)
+        scale = 1.0 / (d ** 0.5)
+        rows = _row_ids(s)
+        dv = int(g.shape[-1])
         # recompute the probs (the fused forward never materializes them)
-        e = _scheduled(sched, s, q_r.shape[1], "attention_bwd_e", q_r, k_r)
-        probs = ref.row_softmax_ref(rowptr, colind, e * scale)
+        e = _scheduled(sched, s, d, "attention_bwd_e", q_r, k_r, heads=heads)
+        with obs.op_scope("bwd", "attention_softmax", d, "baseline"):
+            probs = kx.row_softmax(rows, e * scale)
         t, perm = s.transpose_with_perm()
         # grad_V = A^T(probs) @ g
-        gv = _scheduled(
-            sched, t, g.shape[1], "attention_bwd_v", probs[perm], g
-        )
+        probs, g = barrier((probs, g))
+        gv = _scheduled(sched, t, dv, "attention_bwd_v", probs[..., perm], g,
+                        heads=heads)
         # grad w.r.t. probs: per-edge <g_i, V_j>, then the softmax VJP
-        gp = _scheduled(sched, s, g.shape[1], "attention_bwd_p", g, v_r)
-        gl = ref.row_softmax_bwd_ref(rowptr, colind, probs, gp) * scale
-        gq = _scheduled(sched, s, k_r.shape[1], "attention_bwd_q", gl, k_r)
-        gk = _scheduled(
-            sched, t, q_r.shape[1], "attention_bwd_k", gl[perm], q_r
-        )
+        gv, g, v_r = barrier((gv, g, v_r))
+        gp = _scheduled(sched, s, dv, "attention_bwd_p", g, v_r, heads=heads)
+        with obs.op_scope("bwd", "attention_softmax_bwd", d, "baseline"):
+            gl = kx.row_softmax_bwd(rows, probs, gp) * scale
+        gl, k_r = barrier((gl, k_r))
+        gq = _scheduled(sched, s, d, "attention_bwd_q", gl, k_r, heads=heads)
+        gq, gl, q_r = barrier((gq, gl, q_r))
+        gk = _scheduled(sched, t, d, "attention_bwd_k", gl[..., perm], q_r,
+                        heads=heads)
         return gq.astype(q_r.dtype), gk.astype(k_r.dtype), gv.astype(v_r.dtype)
 
     _f.defvjp(_fwd, _bwd)

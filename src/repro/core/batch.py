@@ -315,10 +315,16 @@ class BatchScheduler:
         return self._transfer_probe_free.value
 
     # ---------------------------------------------------------- decide
-    def decide(self, csr: CSR, f: int, op: str) -> Decision:
+    def decide(self, csr: CSR, f: int, op: str, heads: int = 1) -> Decision:
         """Bucketed decide: O(feature extraction) on the hot path; any
         probing is pulled from the shared budget (at most
-        `max_probes_per_decide` bucket probes per call)."""
+        `max_probes_per_decide` bucket probes per call). Buckets hold
+        single-head ops only: a multi-head op is decided per graph, by
+        `AutoSage`."""
+        if heads != 1:
+            raise NotImplementedError(
+                f"{op} with {heads} heads: buckets hold single-head ops; "
+                "decide it with AutoSage")
         t0 = time.perf_counter()
         with obs.span("decide", op=op, f=f, scheduler="batch"):
             with obs.span("features", op=op):

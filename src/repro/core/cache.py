@@ -4,7 +4,8 @@ Two key kinds live side by side (schema v5):
 
   exact   ``{device}|{graph_sig}|F={f}|{op}|a={alpha}`` — the paper's
           "(device, graph signature, F, op)" plus the guardrail alpha,
-          since a different alpha can change the decision.
+          since a different alpha can change the decision. A multi-head
+          op adds its head count after F: ``F={f}|H={heads}|...``.
   bucket  ``bucket|{device}|{bucket_sig}|F={f}|{op}|a={alpha}`` — one
           decision shared by every graph that canonicalizes into the
           same schedule bucket (core/batch.py); this is what lets a
@@ -166,9 +167,11 @@ class CacheKey:
     f: int
     op: str
     alpha: float
+    heads: int = 1
 
     def format(self) -> str:
-        body = f"{self.device}|{self.sig}|F={self.f}|{self.op}|a={self.alpha}"
+        f = f"F={self.f}" if self.heads == 1 else f"F={self.f}|H={self.heads}"
+        body = f"{self.device}|{self.sig}|{f}|{self.op}|a={self.alpha}"
         return f"{_BUCKET_PREFIX}|{body}" if self.kind == "bucket" else body
 
 
@@ -180,15 +183,19 @@ def parse_key(key: str) -> Optional[CacheKey]:
     if parts and parts[0] == _BUCKET_PREFIX:
         kind = "bucket"
         parts = parts[1:]
+    h_part = "H=1"
+    if len(parts) == 6:
+        h_part = parts.pop(3)
     if len(parts) != 5:
         return None
     device, sig, f_part, op, a_part = parts
-    if not f_part.startswith("F=") or not a_part.startswith("a="):
+    if not (f_part.startswith("F=") and h_part.startswith("H=")
+            and a_part.startswith("a=")):
         return None
     try:
         return CacheKey(
             kind=kind, device=device, sig=sig, f=int(f_part[2:]), op=op,
-            alpha=float(a_part[2:]),
+            alpha=float(a_part[2:]), heads=int(h_part[2:]),
         )
     except ValueError:
         return None
@@ -251,8 +258,9 @@ class ScheduleCache:
             return {}
 
     @staticmethod
-    def key(device_sig: str, graph_sig: str, f: int, op: str, alpha: float) -> str:
-        return CacheKey("exact", device_sig, graph_sig, f, op, alpha).format()
+    def key(device_sig: str, graph_sig: str, f: int, op: str, alpha: float,
+            heads: int = 1) -> str:
+        return CacheKey("exact", device_sig, graph_sig, f, op, alpha, heads).format()
 
     @staticmethod
     def bucket_key(device_sig: str, bucket_sig: str, f: int, op: str, alpha: float) -> str:

@@ -8,6 +8,7 @@ winner lands in the probed top-k; the guardrail absorbs estimate error.
 """
 from __future__ import annotations
 
+import dataclasses
 import math
 from typing import Dict, Iterable
 
@@ -31,7 +32,10 @@ def estimates_for(
     re-rank (core/transfer.py) both call it, so a peer's `est_ms` at
     probe time and the local re-estimate are guaranteed to come from the
     same model — the residual probe/est is then a pure device+input
-    calibration term, not a model-version artifact."""
+    calibration term, not a model-version artifact. H heads of width F
+    are costed as one op of width H*F."""
+    if feat.heads != 1:
+        feat = dataclasses.replace(feat, f=feat.f * feat.heads, heads=1)
     return {
         v.full_name(): estimate(feat, hw, v.name, v.knobs) * 1e3
         for v in variants

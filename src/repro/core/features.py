@@ -211,9 +211,13 @@ class InputFeatures:
     # estimated dense-W ELL width at rb=bc=8 (0 = unknown: estimates
     # fall back to the legacy nnz-multiplier model)
     ell_width_est: float = 0.0
+    # attention heads computed in one op: dense operands are (n, H, f)
+    # and edge values (H, nnz); only variants with a head axis apply
+    # (core/registry.py) and H is part of the cache key
+    heads: int = 1
 
     @staticmethod
-    def from_csr(csr: CSR, f: int, op: str) -> "InputFeatures":
+    def from_csr(csr: CSR, f: int, op: str, heads: int = 1) -> "InputFeatures":
         qs = csr.degree_quantiles((0.5, 0.9, 0.99, 1.0))
         nnz = csr.nnz
         waste, w_est = _block_padding_estimate(csr)
@@ -235,6 +239,7 @@ class InputFeatures:
             dup_edges=(csr.has_duplicate_edges() if op == "attention" else False),
             padding_waste=waste,
             ell_width_est=w_est,
+            heads=heads,
         )
 
     def hub_threshold(self) -> int:

@@ -73,18 +73,21 @@ def decide_attention(
     seed: int = 0,
     stage_breakdown: bool = False,
     allow_transfer: bool = True,
+    heads: int = 1,
 ) -> AttentionDecision:
     """estimate -> end-to-end probe -> guardrail -> cache, at pipeline
-    granularity. ``d`` is the head dimension (the F of the cache key).
+    granularity. ``d`` is the head dimension (the F of the cache key);
+    ``heads`` attention heads run as one op, one decision per
+    (graph, heads, d).
     Like the per-op decide, an exact-key miss consults peer device
     classes' probed rankings first (core/transfer.py) — a confident
     re-rank under the local roofline skips the end-to-end probe."""
     t0 = time.perf_counter()
-    with obs.span("decide", op="attention", f=d, scheduler="exact"):
+    with obs.span("decide", op="attention", f=d, heads=heads, scheduler="exact"):
         try:
             decision, tier = _decide_attention_impl(
                 sage, csr, d, seed=seed, stage_breakdown=stage_breakdown,
-                allow_transfer=allow_transfer,
+                allow_transfer=allow_transfer, heads=heads,
             )
         except ReplayMiss:
             raise  # the replay contract stays loud — never rescued
@@ -95,7 +98,7 @@ def decide_attention(
             # decision machinery still yields a runnable 3-kernel
             # baseline decision (uncached — never a poisoned pin)
             resilience.record_fault("decide", "", "attention", exc)
-            decision, tier = _rescue_attention(sage, csr, d), "fault"
+            decision, tier = _rescue_attention(sage, csr, d, heads), "fault"
     obs.REGISTRY.inc(
         "autosage_decides_total", op="attention", tier=tier, scheduler="exact"
     )
@@ -106,8 +109,9 @@ def decide_attention(
     return decision
 
 
-def _rescue_attention(sage: AutoSage, csr: CSR, d: int) -> "AttentionDecision":
-    feat = InputFeatures.from_csr(csr, d, "attention")
+def _rescue_attention(sage: AutoSage, csr: CSR, d: int,
+                      heads: int = 1) -> "AttentionDecision":
+    feat = InputFeatures.from_csr(csr, d, "attention", heads)
     base = registry.baseline(feat, sage.hw)
     return AttentionDecision(
         op="attention", choice="baseline", variant=base, guardrail=None,
@@ -123,11 +127,13 @@ def _decide_attention_impl(
     seed: int = 0,
     stage_breakdown: bool = False,
     allow_transfer: bool = True,
+    heads: int = 1,
 ) -> tuple:
     """decide_attention body; returns (decision, accounting tier)."""
     with obs.span("features", op="attention"):
-        feat = InputFeatures.from_csr(csr, d, "attention")
-    key = ScheduleCache.key(device_sig(), feat.graph_sig, d, "attention", sage.alpha)
+        feat = InputFeatures.from_csr(csr, d, "attention", heads)
+    key = ScheduleCache.key(device_sig(), feat.graph_sig, d, "attention", sage.alpha,
+                            heads)
 
     cands = registry.candidates(feat, sage.hw)
     base = registry.baseline(feat, sage.hw)
@@ -187,7 +193,7 @@ def _decide_attention_impl(
     if short:
         with obs.span("probe", op="attention", n_candidates=len(short) + 1):
             outcome = sage.probe_candidates(
-                csr, base, short, default_probe_args("attention", d, seed),
+                csr, base, short, default_probe_args("attention", d, seed, heads),
                 seed=seed,
             )
         obs.REGISTRY.inc("autosage_probe_passes_total", op="attention")
@@ -209,7 +215,8 @@ def _decide_attention_impl(
 
     stage_ms: Dict[str, float] = {}
     if stage_breakdown:
-        stage_ms = probe_stage_breakdown(sage, csr, d, variant, seed=seed)
+        stage_ms = probe_stage_breakdown(sage, csr, d, variant, seed=seed,
+                                         heads=heads)
 
     decision = AttentionDecision(
         op="attention", choice=gr.choice, variant=variant, guardrail=gr,
@@ -244,7 +251,8 @@ def attention_forward(sage: AutoSage, csr: CSR, q, k, v, seed: int = 0):
 
 # ---------------------------------------------------------------------
 def probe_stage_breakdown(
-    sage: AutoSage, csr: CSR, d: int, variant: registry.Variant, seed: int = 0
+    sage: AutoSage, csr: CSR, d: int, variant: registry.Variant, seed: int = 0,
+    heads: int = 1,
 ) -> Dict[str, float]:
     """Median per-stage ms of ``variant`` on the probe subgraph.
 
@@ -254,7 +262,7 @@ def probe_stage_breakdown(
     end-to-end probe_ms, not here). The fused kernel is one stage.
     """
     sub = probe_mod.induced_subgraph(csr, frac=sage.probe_frac, seed=seed)
-    q, k, v = default_probe_args("attention", d, seed)(sub)
+    q, k, v = default_probe_args("attention", d, seed, heads)(sub)
     q, k, v = jnp.asarray(q), jnp.asarray(k), jnp.asarray(v)
 
     def _med(fn, name):

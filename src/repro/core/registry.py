@@ -1088,7 +1088,10 @@ def candidates(
         vs = _attention_variants(feat, include_pallas, interpret)
     else:
         raise KeyError(feat.op)
-    return [v for v in vs if v.applicable(feat, hw)]
+    # only the XLA baselines have a head axis: (n, H, D) operands and
+    # (H, nnz) edge values
+    return [v for v in vs
+            if (feat.heads == 1 or v.is_baseline) and v.applicable(feat, hw)]
 
 
 def baseline(feat: InputFeatures, hw: HardwareSpec) -> Variant:

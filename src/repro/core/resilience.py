@@ -467,14 +467,20 @@ class CircuitBreaker:
 
 
 def _infer_f(op: str, args: tuple) -> int:
-    """Feature width from the runtime operands (the fallback stages are
-    built lazily, after the decision object is long gone)."""
+    """Feature width (per head) from the runtime operands (the fallback
+    stages are built lazily, after the decision object is long gone)."""
     from repro.core import features as features_mod
 
     kind = features_mod.op_kind(op)
     if kind == "spmm":
-        return int(args[-1].shape[1])
-    return int(args[0].shape[1])
+        return int(args[-1].shape[-1])
+    return int(args[0].shape[-1])
+
+
+def _infer_heads(args: tuple) -> int:
+    """Heads of the runtime operands: the last is dense, (n, F) or
+    (n, H, F), for every op kind."""
+    return int(args[-1].shape[1]) if args[-1].ndim == 3 else 1
 
 
 def reference_runner(csr, op: str) -> Callable:
@@ -528,7 +534,8 @@ def fallback_stages(csr, op: str, choice: str, variant, hw) -> List[Tuple]:
         from repro.core import registry
         from repro.core.features import InputFeatures
 
-        feat = InputFeatures.from_csr(csr, _infer_f(op, args), op)
+        feat = InputFeatures.from_csr(csr, _infer_f(op, args), op,
+                                      _infer_heads(args))
         base = registry.baseline(feat, hw)
         with obs.span("prepare", op=op, choice="baseline"), \
                 jax.ensure_compile_time_eval():

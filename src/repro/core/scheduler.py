@@ -57,8 +57,10 @@ class ProbeOutcome:
     iter_ms: float  # steady-state probe iterations only
 
 
-def default_probe_args(op: str, f: int, seed: int = 0) -> Callable[[CSR], tuple]:
-    """Random dense operands of width f, shaped for ``op``, per subgraph.
+def default_probe_args(op: str, f: int, seed: int = 0,
+                       heads: int = 1) -> Callable[[CSR], tuple]:
+    """Random dense operands of width f, shaped for ``op``, per subgraph;
+    with ``heads`` > 1, (n, heads, f) operands and (heads, nnz) values.
 
     Grad ops route through their structural compute kind, so the slope
     probe times cotangent-shaped operands: for "spmm_bwd_b" (an SpMM over
@@ -69,6 +71,7 @@ def default_probe_args(op: str, f: int, seed: int = 0) -> Callable[[CSR], tuple]
     """
     kind = features_mod.op_kind(op)
     dynamic = features_mod.op_dynamic_vals(op)
+    per_node = (f,) if heads == 1 else (heads, f)
 
     def fn(sub: CSR) -> tuple:
         # per-subgraph stream: the 1x and 2x slope-probe subgraphs share
@@ -76,21 +79,20 @@ def default_probe_args(op: str, f: int, seed: int = 0) -> Callable[[CSR], tuple]
         # operands and let the 2x probe read them out of a warm cache,
         # biasing the slope low
         rng = np.random.default_rng((seed, sub.n_rows, sub.nnz))
+        def draw(*shape: int) -> np.ndarray:
+            return rng.standard_normal(shape).astype(np.float32)
+
         if kind == "spmm":
-            args = (rng.standard_normal((sub.n_cols, f)).astype(np.float32),)
+            args = (draw(sub.n_cols, *per_node),)
             if dynamic:
-                vals = rng.standard_normal((sub.nnz,)).astype(np.float32)
-                return (vals,) + args
+                per_edge = (sub.nnz,) if heads == 1 else (heads, sub.nnz)
+                return (draw(*per_edge),) + args
             return args
         if kind == "sddmm":
-            x = rng.standard_normal((sub.n_rows, f)).astype(np.float32)
-            y = rng.standard_normal((sub.n_cols, f)).astype(np.float32)
-            return (x, y)
+            return (draw(sub.n_rows, *per_node), draw(sub.n_cols, *per_node))
         if kind == "attention":
-            q = rng.standard_normal((sub.n_rows, f)).astype(np.float32)
-            k = rng.standard_normal((sub.n_cols, f)).astype(np.float32)
-            v = rng.standard_normal((sub.n_cols, f)).astype(np.float32)
-            return (q, k, v)
+            return (draw(sub.n_rows, *per_node), draw(sub.n_cols, *per_node),
+                    draw(sub.n_cols, *per_node))
         raise KeyError(op)
 
     return fn
@@ -329,11 +331,14 @@ class AutoSage:
         probe_args_fn: Optional[Callable[[CSR], tuple]] = None,
         seed: int = 0,
         allow_transfer: bool = True,
+        heads: int = 1,
     ) -> Decision:
         """The paper's `autosage_decide(features, F, op)`.
 
         probe_args_fn(sub_csr) -> dense args for one probe invocation;
-        defaults to random dense operands of width F.
+        defaults to random dense operands of width F. ``heads`` > 1
+        decides one op over (n, heads, F) operands, keyed by its head
+        count too.
 
         On an exact-key miss, a peer device class's probed entry for the
         SAME graph can short-circuit the probe (estimate-space transfer,
@@ -345,11 +350,11 @@ class AutoSage:
         batch scheduler's confirm/drift re-probes use it.
         """
         t0 = time.perf_counter()
-        with obs.span("decide", op=op, f=f, scheduler="exact"):
+        with obs.span("decide", op=op, f=f, heads=heads, scheduler="exact"):
             try:
                 decision, tier = self._decide_impl(
                     csr, f, op, probe_args_fn=probe_args_fn, seed=seed,
-                    allow_transfer=allow_transfer,
+                    allow_transfer=allow_transfer, heads=heads,
                 )
             except ReplayMiss:
                 raise  # the replay contract stays loud — never rescued
@@ -361,7 +366,8 @@ class AutoSage:
                 # constructible and always runnable (its run path still
                 # has the reference-oracle fallback under it)
                 resilience.record_fault("decide", "", op, exc)
-                decision, tier = self._rescue_decision(csr, f, op), "fault"
+                decision = self._rescue_decision(csr, f, op, heads)
+                tier = "fault"
         obs.REGISTRY.inc(
             "autosage_decides_total", op=op, tier=tier, scheduler="exact"
         )
@@ -371,11 +377,12 @@ class AutoSage:
         )
         return decision
 
-    def _rescue_decision(self, csr: CSR, f: int, op: str) -> Decision:
+    def _rescue_decision(self, csr: CSR, f: int, op: str,
+                         heads: int = 1) -> Decision:
         """Provisional baseline decision for the decide-path rescue: not
         cached (the fault may be environmental and transient), never a
         poisoned pin."""
-        feat = InputFeatures.from_csr(csr, f, op)
+        feat = InputFeatures.from_csr(csr, f, op, heads)
         base = registry.baseline(feat, self.hw)
         return Decision(
             op=op, choice="baseline", variant=base, guardrail=None,
@@ -391,12 +398,14 @@ class AutoSage:
         probe_args_fn: Optional[Callable[[CSR], tuple]] = None,
         seed: int = 0,
         allow_transfer: bool = True,
+        heads: int = 1,
     ) -> tuple:
         """decide() body; returns (Decision, tier) where tier is the
         accounting label "cache" | "transfer" | "probe"."""
         with obs.span("features", op=op):
-            feat = InputFeatures.from_csr(csr, f, op)
-        key = ScheduleCache.key(device_sig(), feat.graph_sig, f, op, self.alpha)
+            feat = InputFeatures.from_csr(csr, f, op, heads)
+        key = ScheduleCache.key(device_sig(), feat.graph_sig, f, op, self.alpha,
+                                heads)
 
         cands = registry.candidates(feat, self.hw)
         base = registry.baseline(feat, self.hw)
@@ -469,7 +478,7 @@ class AutoSage:
             with obs.span("probe", op=op, n_candidates=len(short) + 1):
                 outcome = self.probe_candidates(
                     csr, base, short,
-                    probe_args_fn or default_probe_args(op, f, seed),
+                    probe_args_fn or default_probe_args(op, f, seed, heads),
                     seed=seed,
                 )
             obs.REGISTRY.inc("autosage_probe_passes_total", op=op)
@@ -607,7 +616,7 @@ class AutoSage:
     # ---- pipeline-level CSR attention (core/pipeline.py) -------------
     def decide_attention(
         self, csr: CSR, d: int, seed: int = 0, stage_breakdown: bool = False,
-        allow_transfer: bool = True,
+        allow_transfer: bool = True, heads: int = 1,
     ):
         """Joint decision over composed {sddmm x softmax x spmm} pipelines
         and the fused Pallas kernel; cached under op="attention"."""
@@ -615,7 +624,7 @@ class AutoSage:
 
         return pipeline.decide_attention(
             self, csr, d, seed=seed, stage_breakdown=stage_breakdown,
-            allow_transfer=allow_transfer,
+            allow_transfer=allow_transfer, heads=heads,
         )
 
     def attention(self, csr: CSR, q, k, v, seed: int = 0):

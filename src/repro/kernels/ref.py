@@ -2,7 +2,8 @@
 guardrail baseline semantics.
 
 CSR device representation: rowptr int32[n+1], colind int32[nnz],
-val float[nnz] (or None => ones).
+val float[nnz] (or None => ones). Multi-head operands carry heads as an
+axis: dense (n, H, D), per-edge values (H, nnz), one row per head.
 """
 from __future__ import annotations
 
@@ -17,6 +18,17 @@ def _row_ids(rowptr: jax.Array, nnz: int) -> jax.Array:
     return jnp.searchsorted(rowptr, jnp.arange(nnz, dtype=rowptr.dtype), side="right") - 1
 
 
+def _per_edge(val: jax.Array, gathered: jax.Array) -> jax.Array:
+    """Edge values against gathered rows: (nnz,) against (nnz, F), and
+    (H, nnz) against (nnz, H, D)."""
+    return (val.T if gathered.ndim == 3 else val)[..., None].astype(gathered.dtype)
+
+
+def _heads_major(e: jax.Array) -> jax.Array:
+    """Per-edge sums of (nnz,) or (nnz, H) as the edge values: (H, nnz)."""
+    return e.T if e.ndim == 2 else e
+
+
 def spmm_ref(
     rowptr: jax.Array,
     colind: jax.Array,
@@ -29,7 +41,7 @@ def spmm_ref(
     rows = _row_ids(rowptr, nnz)
     gathered = b[colind]  # (nnz, F)
     if val is not None:
-        gathered = gathered * val[:, None].astype(b.dtype)
+        gathered = gathered * _per_edge(val, gathered)
     return jax.ops.segment_sum(gathered, rows, num_segments=n_rows)
 
 
@@ -42,13 +54,16 @@ def sddmm_ref(
     """A~_ij = <X_i, Y_j> for (i,j) in S(A); returns val-vector[nnz]."""
     nnz = colind.shape[0]
     rows = _row_ids(rowptr, nnz)
-    return jnp.sum(x[rows] * y[colind], axis=-1)
+    return _heads_major(jnp.sum(x[rows] * y[colind], axis=-1))
 
 
 def row_softmax_ref(
     rowptr: jax.Array, colind: jax.Array, val: jax.Array
 ) -> jax.Array:
-    """Numerically stable softmax within each CSR row (over its nnz)."""
+    """Numerically stable softmax within each CSR row (over its nnz);
+    (H, nnz) values head by head."""
+    if val.ndim == 2:
+        return jnp.stack([row_softmax_ref(rowptr, colind, v) for v in val])
     n_rows = rowptr.shape[0] - 1
     nnz = colind.shape[0]
     rows = _row_ids(rowptr, nnz)
@@ -95,11 +110,11 @@ def spmm_bwd_ref(
     nnz = colind.shape[0]
     rows = _row_ids(rowptr, nnz)
     # dL/dval_ij = <grad_C_i, B_j>  (an SDDMM on the forward pattern)
-    grad_val = jnp.sum(grad_c[rows] * b[colind], axis=-1)
+    grad_val = _heads_major(jnp.sum(grad_c[rows] * b[colind], axis=-1))
     # dL/dB_j = sum_i val_ij * grad_C_i  (SpMM on the transposed CSR)
     contrib = grad_c[rows]
     if val is not None:
-        contrib = contrib * val[:, None].astype(grad_c.dtype)
+        contrib = contrib * _per_edge(val, contrib)
     grad_b = jax.ops.segment_sum(contrib, colind, num_segments=b.shape[0])
     return grad_val, grad_b
 
@@ -115,9 +130,9 @@ def sddmm_bwd_ref(
     the per-edge cotangent — one on A, one on A^T."""
     nnz = colind.shape[0]
     rows = _row_ids(rowptr, nnz)
-    g = grad_e[:, None].astype(x.dtype)
-    grad_x = jax.ops.segment_sum(g * y[colind], rows, num_segments=x.shape[0])
-    grad_y = jax.ops.segment_sum(g * x[rows], colind, num_segments=y.shape[0])
+    gy, gx = y[colind], x[rows]
+    grad_x = jax.ops.segment_sum(_per_edge(grad_e, gy) * gy, rows, num_segments=x.shape[0])
+    grad_y = jax.ops.segment_sum(_per_edge(grad_e, gx) * gx, colind, num_segments=y.shape[0])
     return grad_x, grad_y
 
 
@@ -128,7 +143,11 @@ def row_softmax_bwd_ref(
     grad_probs: jax.Array,
 ) -> jax.Array:
     """VJP of row_softmax_ref given its *output* probs: per row,
-    grad_logits = p * (grad_p - <p, grad_p>)."""
+    grad_logits = p * (grad_p - <p, grad_p>); (H, nnz) values head by
+    head."""
+    if probs.ndim == 2:
+        return jnp.stack([row_softmax_bwd_ref(rowptr, colind, p, g)
+                          for p, g in zip(probs, grad_probs)])
     n_rows = rowptr.shape[0] - 1
     nnz = colind.shape[0]
     rows = _row_ids(rowptr, nnz)

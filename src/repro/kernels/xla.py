@@ -22,7 +22,34 @@ from repro.sparse.csr import CSR
 # The CSR variants read each edge's row from ``edge_row``, made once on
 # the host, where ref.py's oracles binary-search rowptr on every call.
 # Each body below is its oracle's with the search taken out, op for op,
-# so the two agree bit for bit (tests/test_xla_row_ids.py).
+# so the two agree bit for bit (tests/test_xla_row_ids.py). Heads are an
+# axis: an (n, H, D) operand is gathered as (H*D)-wide rows and its edge
+# values are (H, nnz), one row per head. (A TPU lays out the last two
+# axes of an array in (8, 128) tiles, so a gathered (nnz, H, D) operand
+# would take 8*128/(H*D) times its bytes, and (nnz, H) values 128/H.)
+# The multi-head SDDMM and SpMM gather in chunks of edges, one chunk
+# after another, so their gathered rows take at most EDGE_CHUNK_BYTES
+# whatever the edge count, and the multi-head softmax sums rows by scans:
+# they agree with the oracles head by head to float32 round-off
+# (tests/test_unimp.py).
+
+# Bytes of one chunk's gathered rows, their lanes padded to 128 as a TPU
+# lays them out.
+EDGE_CHUNK_BYTES = 1 << 30
+
+
+def _rows(a: jax.Array) -> jax.Array:
+    """(n, H, D) as (n, H*D): one row per node."""
+    return a.reshape(a.shape[0], -1) if a.ndim == 3 else a
+
+
+def _edge_chunks(nnz: int, width: int):
+    """[start, stop) edge ranges whose gathered float32 rows of ``width``
+    take at most EDGE_CHUNK_BYTES (at least one edge each)."""
+    lanes = -(-width // 128) * 128
+    size = max(1, EDGE_CHUNK_BYTES // (4 * lanes))
+    return [(s, min(s + size, nnz)) for s in range(0, nnz, size)]
+
 
 def edge_rows(csr: CSR) -> np.ndarray:
     """Row id of every nnz entry, int32[nnz]."""
@@ -39,26 +66,92 @@ def prepare_csr(csr: CSR) -> Dict[str, np.ndarray]:
 
 
 def spmm_csr(aux: Dict, val: jax.Array, b: jax.Array) -> jax.Array:
-    """`ref.spmm_ref` on prepared row ids; ``val`` per edge."""
-    gathered = b[aux["colind"]] * val[:, None].astype(b.dtype)
-    return jax.ops.segment_sum(
-        gathered, aux["edge_row"], num_segments=aux["rowptr"].shape[0] - 1
-    )
+    """`ref.spmm_ref` on prepared row ids; ``val`` per edge, (nnz,) with
+    b (n, F) or (H, nnz) with b (n, H, D)."""
+    n_rows = aux["rowptr"].shape[0] - 1
+    val = val.astype(b.dtype)
+    if b.ndim == 2:
+        gathered = b[aux["colind"]] * val[:, None]
+        return jax.ops.segment_sum(gathered, aux["edge_row"], num_segments=n_rows)
+    heads, d = b.shape[1], b.shape[2]
+    rows, cols, br = aux["edge_row"], aux["colind"], _rows(b)
+    out = jnp.zeros((n_rows, heads * d), b.dtype)
+    for s, e in _edge_chunks(cols.shape[0], heads * d):
+        g = br[cols[s:e]]
+        # each head's D columns by its own value
+        scaled = jnp.concatenate([g[:, h * d:(h + 1) * d] * val[h, s:e, None]
+                                  for h in range(heads)], axis=1)
+        # the next chunk gathers once this one is summed
+        out, br = jax.lax.optimization_barrier((out.at[rows[s:e]].add(scaled), br))
+    return out.reshape(n_rows, heads, d)
 
 
 def sddmm_csr(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
-    """`ref.sddmm_ref` on prepared row ids."""
-    return jnp.sum(x[aux["edge_row"]] * y[aux["colind"]], axis=-1)
+    """`ref.sddmm_ref` on prepared row ids; (H, nnz) values for (n, H, D)
+    operands, each head summing its own D columns of the gathered rows."""
+    if x.ndim == 2:
+        return jnp.sum(x[aux["edge_row"]] * y[aux["colind"]], axis=-1)
+    heads, d = x.shape[1], x.shape[2]
+    rows, cols, xr, yr = aux["edge_row"], aux["colind"], _rows(x), _rows(y)
+    parts = [jnp.zeros((heads, 0), jnp.result_type(x, y))]
+    for s, e in _edge_chunks(cols.shape[0], heads * d):
+        gx, gy = xr[rows[s:e]], yr[cols[s:e]]
+        part = jnp.stack([jnp.sum(gx[:, h * d:(h + 1) * d] * gy[:, h * d:(h + 1) * d], axis=-1)
+                          for h in range(heads)])
+        # the next chunk gathers once this one is summed
+        part, xr, yr = jax.lax.optimization_barrier((part, xr, yr))
+        parts.append(part)
+    return jnp.concatenate(parts, axis=1)
 
 
 def row_softmax(aux: Dict, val: jax.Array) -> jax.Array:
-    """`ref.row_softmax_ref` on prepared row ids."""
+    """`ref.row_softmax_ref` on prepared row ids; (H, nnz) values by row
+    scans (`_row_total`)."""
+    if val.ndim == 2:
+        row_max = _row_total(aux, val, jnp.maximum)
+        shifted = jnp.exp(val - jnp.where(jnp.isfinite(row_max), row_max, 0.0))
+        return shifted / jnp.maximum(_row_total(aux, shifted, jnp.add), 1e-30)
     rows, n_rows = aux["edge_row"], aux["rowptr"].shape[0] - 1
     row_max = jax.ops.segment_max(val, rows, num_segments=n_rows)
     row_max = jnp.where(jnp.isfinite(row_max), row_max, 0.0)
     shifted = jnp.exp(val - row_max[rows])
     denom = jax.ops.segment_sum(shifted, rows, num_segments=n_rows)
     return shifted / jnp.maximum(denom[rows], 1e-30)
+
+
+def row_softmax_bwd(aux: Dict, probs: jax.Array, grad_probs: jax.Array) -> jax.Array:
+    """`ref.row_softmax_bwd_ref` on prepared row ids; (H, nnz) values by
+    row scans (`_row_total`)."""
+    tmp = probs * grad_probs
+    if probs.ndim == 2:
+        return tmp - probs * _row_total(aux, tmp, jnp.add)
+    rows, n_rows = aux["edge_row"], aux["rowptr"].shape[0] - 1
+    row_dot = jax.ops.segment_sum(tmp, rows, num_segments=n_rows)
+    return tmp - probs * row_dot[rows]
+
+
+def _row_total(aux: Dict, x: jax.Array, op) -> jax.Array:
+    """``op`` (max or add) over each row's edges of (H, nnz) values, at
+    every edge of the row. Rows are runs of edges, so two passes of
+    log2(nnz) shifted elementwise steps do it, with no scatter or gather:
+    a segmented scan leaves each row's total at its last edge, and a
+    second pass copies that back along the row. (A TPU scatters (nnz, H)
+    values edge by edge, with H of 128 lanes used.) The barrier keeps the
+    compiler from folding each step's row mask into a constant of its
+    own."""
+    rows, nnz = jax.lax.optimization_barrier(aux["edge_row"]), x.shape[1]
+    k = 1
+    while k < nnz:  # edge e: op over its row's edges in (e - 2k, e]
+        same = rows[k:] == rows[:-k]
+        x = jnp.concatenate([x[:, :k], jnp.where(same, op(x[:, :-k], x[:, k:]), x[:, k:])],
+                            axis=1)
+        k *= 2
+    k = 1
+    while k < nnz:  # edge e: the value at its row's last edge
+        same = rows[k:] == rows[:-k]
+        x = jnp.concatenate([jnp.where(same, x[:, k:], x[:, :-k]), x[:, nnz - k:]], axis=1)
+        k *= 2
+    return x
 
 
 # ---------------------------------------------------------------- SpMM

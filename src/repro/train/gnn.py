@@ -1,12 +1,13 @@
-"""GraphSAGE training loops with AutoSAGE-scheduled aggregation.
+"""GNN training loops with AutoSAGE-scheduled aggregation.
 
-One copy of each loop, shared by ``examples/train_gnn.py`` and
-``chip_smoke.py``:
+One copy of each loop, shared by ``examples/train_gnn.py``,
+``chip_smoke.py`` and the benchmark:
 
 - `train_full`: the whole graph per step, one jitted ``value_and_grad``
-  whose forward SpMMs and their backward (``spmm_bwd_b`` on the memoized
-  transpose) are each decided, probed and prepared while the step is
-  traced;
+  whose scheduled sparse ops, forward and backward (``spmm_bwd_b`` on the
+  memoized transpose; the attention backward's pieces), are each decided,
+  probed and prepared while the step is traced. GraphSAGE by default;
+  UniMP (`init_unimp`, `unimp_forward`) through the same loop;
 - `train_minibatch`: a sampled induced subgraph per step, every step's
   decides shared through one `BatchScheduler`.
 """
@@ -44,6 +45,12 @@ def _xent(logits: jax.Array, y: jax.Array) -> jax.Array:
     return -jnp.take_along_axis(logp, y[:, None], 1).mean()
 
 
+def _masked_xent(logits: jax.Array, y: jax.Array, mask: jax.Array) -> jax.Array:
+    """Mean cross-entropy over the nodes where ``mask`` holds."""
+    logp = jnp.take_along_axis(jax.nn.log_softmax(logits), y[:, None], 1)[:, 0]
+    return -jnp.sum(jnp.where(mask, logp, 0.0)) / jnp.sum(mask)
+
+
 @dataclasses.dataclass
 class FullRun:
     losses: List[float]
@@ -64,16 +71,24 @@ def train_full(
     lr: float = 0.05,
     seed: int = 0,
     on_step: Optional[Callable[[int, float, float], None]] = None,
+    init: Callable = init_gnn,
+    forward: Callable = sage_forward,
+    loss_mask: Optional[jax.Array] = None,
 ) -> FullRun:
-    """Full-graph GraphSAGE training with plain SGD. The step is lowered
-    and compiled once, before the loop, so its cost is reported apart
-    from the steady step times."""
-    params = init_gnn(cfg, jax.random.PRNGKey(seed), x.shape[1], n_classes)
+    """Full-graph training with plain SGD: ``init(cfg, key, in_dim,
+    n_classes)`` gives the parameters' layout and ``forward(params, graph,
+    x, sage=...)`` the logits (GraphSAGE by default). The loss is the
+    cross-entropy over every node, or over the nodes of ``loss_mask``.
+    The step is lowered and compiled once, before the loop, so its cost
+    is reported apart from the steady step times."""
+    params = init(cfg, jax.random.PRNGKey(seed), x.shape[1], n_classes)
+
+    def loss_fn(q):
+        logits = forward(q, graph, x, sage=sage)
+        return _xent(logits, y) if loss_mask is None else _masked_xent(logits, y, loss_mask)
 
     def step(p):
-        loss, g = jax.value_and_grad(
-            lambda q: _xent(sage_forward(q, graph, x, sage=sage), y)
-        )(p)
+        loss, g = jax.value_and_grad(loss_fn)(p)
         return loss, jax.tree.map(lambda a, b: a - lr * b, p, g)
 
     t0 = time.perf_counter()

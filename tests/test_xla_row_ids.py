@@ -82,8 +82,23 @@ def _attention(csr, rng):
             jax.jit(ref.csr_attention_ref)(aux["rowptr"], aux["colind"], q, k, v))
 
 
+def _row_softmax(csr, rng):
+    aux = registry._dev(kx.prepare_csr(csr))
+    val = _dense(rng, 1, csr.nnz)[0]
+    return (jax.jit(kx.row_softmax)(aux, val),
+            jax.jit(ref.row_softmax_ref)(aux["rowptr"], aux["colind"], val))
+
+
+def _row_softmax_bwd(csr, rng):
+    aux = registry._dev(kx.prepare_csr(csr))
+    probs, grad = _dense(rng, 1, csr.nnz)[0], _dense(rng, 1, csr.nnz)[0]
+    return (jax.jit(kx.row_softmax_bwd)(aux, probs, grad),
+            jax.jit(ref.row_softmax_bwd_ref)(aux["rowptr"], aux["colind"], probs, grad))
+
+
 KERNELS = {"spmm_gather_segsum": _spmm, "spmm_gather_dyn": _spmm_dyn,
-           "sddmm_gather_dot": _sddmm, "attention_csr": _attention}
+           "sddmm_gather_dot": _sddmm, "attention_csr": _attention,
+           "row_softmax": _row_softmax, "row_softmax_bwd": _row_softmax_bwd}
 
 
 @pytest.mark.parametrize("graph", sorted(GRAPHS))
