@@ -78,10 +78,9 @@ class Variant:
 
 
 def _dev(aux: Dict) -> Dict:
-    return {
-        k: (jnp.asarray(v) if isinstance(v, np.ndarray) else v)
-        for k, v in aux.items()
-    }
+    return jax.tree.map(
+        lambda v: jnp.asarray(v) if isinstance(v, np.ndarray) else v, aux
+    )
 
 
 # jitted once per function; aux dicts are pytree arguments so each new
@@ -89,6 +88,7 @@ def _dev(aux: Dict) -> Dict:
 _spmm_gather_jit = jax.jit(kx.spmm_gather_segsum)
 _spmm_dense_jit = jax.jit(kx.spmm_dense)
 _spmm_ell_jit = jax.jit(kx.spmm_row_ell)
+_spmm_bucket_ell_jit = jax.jit(kx.spmm_bucket_ell, static_argnames="chunk_bytes")
 _sddmm_gather_jit = jax.jit(kx.sddmm_gather_dot)
 
 
@@ -118,10 +118,14 @@ def _gather_fits(rows_x_width: float, f: InputFeatures, hw: HardwareSpec) -> boo
     """The XLA row-ELL kernels gather a (rows, width, F) f32 operand and
     XLA materializes it in HBM (a compile for a described v5e refused
     row_ell and hub_split_ell on a 122k-node Products-shaped graph at
-    F=256 for 21.6 GB and 44.6 GB). A training step keeps a forward and
-    a backward op's buffers plus activations live, so one gather may
-    take at most a quarter of HBM."""
-    return rows_x_width * f.f * 4 <= hw.hbm_bytes // 4
+    F=256 for 21.6 GB and 44.6 GB). A TPU lays the F columns out in 128
+    lanes, so F=47 takes 128 columns' bytes: row_ell's gather on that
+    graph's transpose is 5.4 GB, and on a v5e it ran 91 ms a step where
+    its probe on a subgraph had read it close to bucket_ell. A training
+    step keeps a forward and a backward op's buffers plus activations
+    live, so one gather may take at most a quarter of HBM."""
+    lanes = -(-f.f // 128) * 128
+    return rows_x_width * lanes * 4 <= hw.hbm_bytes // 4
 
 
 def _ell_applicable(f: InputFeatures, hw: HardwareSpec) -> bool:
@@ -161,6 +165,15 @@ def _spmm_variants(feat: InputFeatures) -> List[Variant]:
             prepare=kx.prepare_row_ell,
             build=lambda aux: (lambda b, a=_dev(aux): _spmm_ell_jit(a, b)),
             applicable=lambda f, hw: _ell_applicable(f, hw),
+        ),
+        Variant(
+            name="bucket_ell",
+            op="spmm",
+            prepare=kx.prepare_bucket_ell,
+            build=lambda aux: (lambda b, a=_dev(aux): _spmm_bucket_ell_jit(a, b)),
+            # padding stays near nnz under any skew, and the row chunks
+            # bound the gathered rows to kx.EDGE_CHUNK_BYTES
+            applicable=lambda f, hw: True,
         ),
     ]
     hub_t = int(os.environ.get("AUTOSAGE_HUB_T", feat.hub_threshold()))

@@ -371,6 +371,9 @@ class AutoSage:
         obs.REGISTRY.inc(
             "autosage_decides_total", op=op, tier=tier, scheduler="exact"
         )
+        obs.REGISTRY.inc(
+            "autosage_decide_choice_total", op=op, choice=decision.choice
+        )
         obs.REGISTRY.observe(
             "autosage_decide_ms", (time.perf_counter() - t0) * 1e3,
             op=op, scheduler="exact",

@@ -203,6 +203,135 @@ def spmm_row_ell(aux: Dict, b: jax.Array) -> jax.Array:
                       precision=F32_MXU)
 
 
+# ------------------------------------------------ degree-bucketed ELL
+# Rows sorted by degree fall into at most MAX_BUCKETS buckets, each
+# padded to its widest row (K slots) and to a multiple of 8 rows, and
+# stored slot-major by groups of 8 rows: a flat (rows/8, K, 8) table, so
+# that slot k of a group is one run of 8. A TPU lays out the last two
+# axes of an array in (8, 128) tiles: a 2-D (K, rows) table of a narrow
+# bucket would pad its rows to 128 lanes, while 8 rows of F columns are
+# whole tiles, so the gathered (groups*K*8, F) rows reshape to
+# (groups, K, 8, F) in place and the K slabs add elementwise. No
+# scatter. Row chunks are runs of whole groups, contiguous in the
+# table. The bucket outputs, in degree order, go back in row order by
+# one gather.
+
+MAX_BUCKETS = 24
+# degrees above this are rounded up to a geometric grid of ratio
+# _GRID_RATIO before the bucket search, which keeps the search small
+# whatever the spread of degrees and costs at most 4% of padding
+_EXACT_DEGREE = 32
+_GRID_RATIO = 1.04
+_GROUP = 8  # rows of a group: the sublanes of a TPU tile
+
+
+@jax.tree_util.register_static
+class Slots(tuple):
+    """Slot counts (K) of a layout's buckets: static under jit, as the
+    tables' shapes are."""
+
+
+def bucket_bounds(deg: np.ndarray, max_buckets: int = MAX_BUCKETS) -> np.ndarray:
+    """Upper degree bounds of the buckets of the rows of degree ``deg``
+    (ascending, the last the largest degree), at most ``max_buckets``,
+    chosen to minimise the padded slots: the sum over buckets of the
+    bound times the bucket's rows rounded up to whole groups of 8. Rows
+    of degree 0 belong to no bucket."""
+    deg = np.asarray(deg, np.int64)
+    if not np.any(deg > 0):
+        return np.zeros(0, np.int64)
+    top = int(deg.max())
+    grid = np.ceil(_EXACT_DEGREE * _GRID_RATIO ** np.arange(
+        1, np.log(top / _EXACT_DEGREE) / np.log(_GRID_RATIO) + 2)).astype(np.int64)
+    grid = np.unique(np.concatenate([np.arange(1, _EXACT_DEGREE + 1), grid]))
+    width, count = np.unique(grid[np.searchsorted(grid, deg[deg > 0])],
+                             return_counts=True)
+    width[-1] = top  # the last grid point may lie above the largest degree
+    # best[j]: least padded slots of the rows up to the j-th width in as
+    # many buckets as there have been rounds, the last ending at width
+    # j - 1; cuts[r][j - 1] is where round r's last bucket starts
+    m = width.size
+    below = np.concatenate([[0], np.cumsum(count)])
+    i, j = np.arange(m + 1)[:, None], np.arange(1, m + 1)[None, :]
+    rows = -(-(below[j] - below[i]) // _GROUP) * _GROUP
+    cost = np.where(i < j, width[j - 1] * rows, np.inf)
+    best = np.where(np.arange(m + 1) == 0, 0.0, np.inf)
+    cuts, least = [], []
+    for _ in range(min(max_buckets, m)):
+        total = best[:, None] + cost
+        cuts.append(np.argmin(total, axis=0))
+        best = np.concatenate([[np.inf], total[cuts[-1], np.arange(m)]])
+        least.append(best[m])
+    ends, j = [], m
+    for cut in reversed(cuts[:int(np.argmin(least)) + 1]):
+        ends.append(j)
+        j = int(cut[j - 1])
+    return width[np.array(ends[::-1]) - 1]
+
+
+def prepare_bucket_ell(csr: CSR) -> Dict:
+    """Degree-bucketed, slot-major ELL (`bucket_bounds`): per bucket a
+    flat (rows/8, K, 8) table of column ids and one of values, rows in
+    degree order; padded slots and rows hold column ``n_cols`` (past the
+    last, where the run puts a zero row) and value 0. ``inv`` gives each
+    row its place in the run's output: 0 (a zero row) for degree 0, else
+    its row of its bucket, the buckets one after another after 8 zero
+    rows."""
+    deg = csr.degrees.astype(np.int64)
+    order = np.argsort(deg, kind="stable")
+    bounds = bucket_bounds(deg)
+    val = csr.values_or_ones(np.float32)
+    rowptr = np.asarray(csr.rowptr, np.int64)
+    sorted_deg = deg[order]
+    edges = np.searchsorted(sorted_deg, np.concatenate([[1], bounds + 1]))
+    inv = np.zeros(csr.n_rows, np.int32)
+    cols, vals, slots, at = [], [], [], _GROUP
+    for s, e in zip(edges[:-1], edges[1:]):
+        rows, k = order[s:e], int(sorted_deg[e - 1])
+        n = -(-rows.size // _GROUP) * _GROUP
+        inv[rows] = at + np.arange(rows.size)
+        at += n
+        d, start = np.zeros(n, np.int64), np.zeros(n, np.int64)
+        d[:rows.size], start[:rows.size] = deg[rows], rowptr[rows]
+        d, start = d.reshape(-1, 1, _GROUP), start.reshape(-1, 1, _GROUP)
+        slot = np.arange(k)[None, :, None]
+        real = slot < d
+        pos = np.where(real, start + slot, 0)
+        cols.append(np.where(real, csr.colind[pos], csr.n_cols).astype(np.int32).ravel())
+        vals.append(np.where(real, val[pos], 0.0).astype(np.float32).ravel())
+        slots.append(k)
+    return {"inv": inv, "cols": tuple(cols), "vals": tuple(vals), "slots": Slots(slots)}
+
+
+def bucket_chunks(aux: Dict, width: int, chunk_bytes: int = EDGE_CHUNK_BYTES):
+    """(bucket, start, stop) row ranges of each bucket, whole groups of
+    8 rows, whose gathered float32 rows of ``width``, lanes padded to
+    128, take at most ``chunk_bytes`` (at least one group each)."""
+    lanes = -(-width // 128) * 128
+    out = []
+    for i, (cols, k) in enumerate(zip(aux["cols"], aux["slots"])):
+        rows = cols.shape[0] // k
+        size = max(1, chunk_bytes // (4 * lanes * k * _GROUP)) * _GROUP
+        out += [(i, s, min(s + size, rows)) for s in range(0, rows, size)]
+    return out
+
+
+def spmm_bucket_ell(aux: Dict, b: jax.Array,
+                    chunk_bytes: int = EDGE_CHUNK_BYTES) -> jax.Array:
+    """SpMM on `prepare_bucket_ell`'s layout: per row chunk of a bucket,
+    gather its rows of b, scale them and add the K slabs in ``b``'s
+    dtype; padded slots gather a zero row at value 0."""
+    f = b.shape[1]
+    bz = jnp.concatenate([b, jnp.zeros((1, f), b.dtype)])
+    parts = [jnp.zeros((_GROUP, f), b.dtype)]
+    for i, s, e in bucket_chunks(aux, f, chunk_bytes):
+        k = aux["slots"][i]
+        cols, val = aux["cols"][i][s * k:e * k], aux["vals"][i][s * k:e * k]
+        g = (bz[cols] * val[:, None].astype(b.dtype)).reshape(-1, k, _GROUP, f)
+        parts.append(jnp.sum(g, axis=1).reshape(e - s, f))
+    return jnp.concatenate(parts)[aux["inv"]]
+
+
 def prepare_hub_split_ell(csr: CSR, hub_threshold: int) -> Dict[str, np.ndarray]:
     """Two ELL partitions split by degree (CTA-per-hub analogue)."""
     from repro.sparse.bsr import hub_split

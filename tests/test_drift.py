@@ -260,7 +260,7 @@ def test_waste_bin_shift_flags_drift():
 
 # ------------------------------------------------- real-kernel flip (slow)
 @pytest.mark.slow
-def test_drift_reprobe_flips_decision_real_kernels():
+def test_drift_reprobe_flips_decision_real_kernels(monkeypatch):
     """End-to-end with wall-clock observations: a uniform deg-18 stream
     pins row_ell; the same bucket then fills with hidden-hub graphs
     (deg_max 140 — bins unchanged, row_ell still applicable, its padded
@@ -270,6 +270,16 @@ def test_drift_reprobe_flips_decision_real_kernels():
 
     import jax
     import jax.numpy as jnp
+
+    from repro.core import registry
+
+    # bucket_ell pads neither stream (one bucket of width 18, then
+    # buckets around the hubs) and can win both on a CPU, so the flip
+    # need not happen: the pool here is the one whose best kernel the
+    # hubs change
+    pool = registry._spmm_variants
+    monkeypatch.setattr(registry, "_spmm_variants", lambda feat: [
+        v for v in pool(feat) if v.name != "bucket_ell"])
 
     # at F=8 row_ell's one (n, K, F) gather and dense reduce beat the
     # baseline's gather and scatter-add about 1.5x on the deg-18 rows (a

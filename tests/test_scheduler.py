@@ -152,3 +152,19 @@ def test_registry_applicability_gates():
     assert "row_ell" not in names      # deg_max >> avg: padding explosion
     assert "hub_split_ell" in names    # skewed tail: the hub split applies
     assert "gather_segsum" in names
+
+
+@pytest.mark.parametrize("n_rows, fits", [(122_451, False), (40_000, True)])
+def test_row_ell_gather_gate_counts_lanes(n_rows, fits):
+    """Products' transpose at F=47: row_ell's (n, 86, 47) gather takes
+    128 lanes a row on a TPU, 5.4 GB, over a quarter of a v5e's HBM; a
+    third of the rows fits. bucket_ell applies either way."""
+    feat = InputFeatures(
+        n_rows=n_rows, n_cols=n_rows, nnz=int(n_rows * 50.5), avg_deg=50.5,
+        deg_p50=50, deg_p90=60, deg_p99=68, deg_max=86, skew=1.7,
+        density=50.5 / n_rows, f=47, op="spmm_bwd_b", graph_sig="t",
+        f_mod_4=False,
+    )
+    names = {v.name for v in registry.candidates(feat, HardwareSpec.tpu_v5e(),
+                                                 include_pallas=False)}
+    assert ("row_ell" in names) == fits and "bucket_ell" in names
