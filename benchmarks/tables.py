@@ -176,7 +176,8 @@ def table_vec_ablation(full: bool = False) -> List[Tuple]:
 
 
 def table_split(full: bool = False) -> List[Tuple]:
-    """Table 10: CTA-per-hub split vs baseline on hub-skewed graphs, F=128."""
+    """Table 10's hub-skewed graphs, F=128: bucket_ell, whose degree
+    buckets give the hubs their own slot widths, vs the baseline."""
     rows = []
     cases = [
         ("N=20k,hub=5k,other=64", table10_graph(20_000, 5_000, 64)),
@@ -190,11 +191,11 @@ def table_split(full: bool = False) -> List[Tuple]:
         hw = HardwareSpec.cpu()
         base = registry.baseline(feat, hw)
         t_base = _measure_full(lambda r=base.build(base.prepare(csr)): r(b), iters=3)
-        splits = [v for v in registry.candidates(feat, hw, include_pallas=False) if v.name == "hub_split_ell"]
-        t_split = _measure_full(lambda r=splits[0].build(splits[0].prepare(csr)): r(b), iters=3)
-        rows.append((name, round(t_base, 3), round(t_split, 3), round(t_base / t_split, 3)))
-        print(f"  [split] {name}: baseline={t_base:.3f}ms split={t_split:.3f}ms speedup={t_base/t_split:.3f}")
-    write_csv(f"{OUT}/table10_split.csv", ["setting", "baseline_ms", "split_ms", "speedup"], rows)
+        bucket = next(v for v in registry.candidates(feat, hw, include_pallas=False) if v.name == "bucket_ell")
+        t_bucket = _measure_full(lambda r=bucket.build(bucket.prepare(csr)): r(b), iters=3)
+        rows.append((name, round(t_base, 3), round(t_bucket, 3), round(t_base / t_bucket, 3)))
+        print(f"  [split] {name}: baseline={t_base:.3f}ms bucket_ell={t_bucket:.3f}ms speedup={t_base/t_bucket:.3f}")
+    write_csv(f"{OUT}/table10_split.csv", ["setting", "baseline_ms", "bucket_ell_ms", "speedup"], rows)
     return rows
 
 
@@ -230,7 +231,7 @@ def probe_overhead(full: bool = False) -> List[Tuple]:
 def csr_attention_pipeline(full: bool = False) -> List[Tuple]:
     """§8.7 at pipeline granularity: composed {sddmm x softmax x spmm}
     candidates and the fused Pallas kernel, decided jointly by
-    AutoSage.attention; reports end-to-end candidate timings, the chosen
+    `AutoSage.decide(..., "attention")`; reports end-to-end candidate timings, the chosen
     pipeline's full-graph runtime vs the 3-kernel baseline, and the
     per-stage breakdown of the winner."""
     csr = products_like(scale=0.05 if full else 0.01).dedup_edges()
@@ -244,12 +245,10 @@ def csr_attention_pipeline(full: bool = False) -> List[Tuple]:
     # time the decision as a user pays it (no diagnostic breakdown), then
     # fetch the per-stage breakdown of the cached choice separately
     t0 = time.perf_counter()
-    decision = sage.decide_attention(csr, f)
+    decision = sage.decide(csr, f, "attention")
     t_decide = (time.perf_counter() - t0) * 1e3
     from repro.core.pipeline import probe_stage_breakdown
-    decision.stage_ms.update(
-        probe_stage_breakdown(sage, csr, f, decision.variant)
-    )
+    stage_ms = probe_stage_breakdown(sage, csr, f, decision.variant)
 
     feat = InputFeatures.from_csr(csr, f, "attention")
     base_v = registry.baseline(feat, sage.hw)
@@ -269,7 +268,7 @@ def csr_attention_pipeline(full: bool = False) -> List[Tuple]:
     ]
     for name, ms in sorted(decision.probe_ms.items(), key=lambda kv: kv[1]):
         rows.append(("probe", name, round(ms, 3), "-"))
-    for stage, ms in decision.stage_ms.items():
+    for stage, ms in stage_ms.items():
         rows.append(("stage", stage, round(ms, 3), "-"))
     for kind, name, ms, sp in rows:
         print(f"  [csr-attn] {kind:7s} {name:42s} {ms:10.3f}ms speedup={sp}")
@@ -1057,7 +1056,7 @@ def smoke(full: bool = False) -> List[Tuple]:
     q = jnp.asarray(rng.standard_normal((csr.n_rows, f)).astype(np.float32))
     k = jnp.asarray(rng.standard_normal((csr.n_cols, f)).astype(np.float32))
     v = jnp.asarray(rng.standard_normal((csr.n_cols, f)).astype(np.float32))
-    d_attn = sage.decide_attention(csr, f)
+    d_attn = sage.decide(csr, f, "attention")
     out_a = api.attention(csr, q, k, v, sage=sage, differentiable=False)
     exp = ref.csr_attention_ref(
         jnp.asarray(csr.rowptr), jnp.asarray(csr.colind), q, k, v

@@ -35,12 +35,10 @@ from repro.core.cache import (
     parse_key,
 )
 from repro.core.guardrail import apply_guardrail, GuardrailDecision
-from repro.core.pipeline import AttentionDecision
 from repro.core.batch import BatchScheduler
 
 __all__ = [
     "AutoSage",
-    "AttentionDecision",
     "BatchScheduler",
     "CacheKey",
     "CacheLockTimeout",

@@ -35,14 +35,6 @@ from repro.kernels import xla as kx
 from repro.sparse.csr import CSR
 
 
-def _decide(sched, csr: CSR, f: int, op: str, heads: int):
-    """One scheduled decision; AutoSage's pipeline-level attention decide
-    when available (BatchScheduler buckets attention via generic decide)."""
-    if op == "attention" and hasattr(sched, "decide_attention"):
-        return sched.decide_attention(csr, f, heads=heads)
-    return sched.decide(csr, f, op, heads=heads)
-
-
 def _scheduled(sched, csr: CSR, f: int, op: str, *args, heads: int = 1):
     """decide + (memoized) prepare + run one scheduled op; ``f`` is the
     width per head, and ``heads`` > 1 runs (n, heads, f) operands.
@@ -61,7 +53,7 @@ def _scheduled(sched, csr: CSR, f: int, op: str, *args, heads: int = 1):
     kind = "bwd" if "_bwd" in op else "fwd"
     with obs.span(f"{kind}.{op}", op=op, heads=heads):
         try:
-            d = _decide(sched, csr, int(f), op, heads)
+            d = sched.decide(csr, int(f), op, heads=heads)
             runner = sched.build_runner(csr, d)
         except Exception as exc:
             # defense in depth for non-AutoSage scheds (duck-typed custom
@@ -184,8 +176,8 @@ def _row_ids(s: CSR) -> Dict:
 
 
 def attention(csr: CSR, q: jax.Array, k: jax.Array, v: jax.Array, *, sched):
-    """CSR attention (SDDMM -> row-softmax -> SpMM) through the
-    pipeline-level scheduler, differentiable.
+    """CSR attention (SDDMM -> row-softmax -> SpMM), decided as one
+    op="attention" pipeline by the scheduler, differentiable.
 
     q/k/v are (n, D) for one head or (n, H, D) for H heads computed as
     one op, with (H, nnz) edge values in every piece and one decision

@@ -652,15 +652,10 @@ class BatchScheduler:
             # allow_transfer=False: this IS the measurement that confirms
             # (or flips) a transferred choice and re-pins drifted buckets
             # — an estimate-space shortcut here would be circular
-            if st.rep_feat.op == "attention":
-                d = self.sage.decide_attention(
-                    st.rep_csr, st.rep_feat.f, seed=seed, allow_transfer=False
-                )
-            else:
-                d = self.sage.decide(
-                    st.rep_csr, st.rep_feat.f, st.rep_feat.op, seed=seed,
-                    allow_transfer=False,
-                )
+            d = self.sage.decide(
+                st.rep_csr, st.rep_feat.f, st.rep_feat.op, seed=seed,
+                allow_transfer=False,
+            )
             if was_pending_transfer:
                 st.transfer_verdict = (
                     "confirmed" if d.choice == st.transfer_choice else "flipped"

@@ -54,7 +54,8 @@ from repro.core import faultinject, obs
 DEFAULT_PATH = os.environ.get("AUTOSAGE_CACHE", "autosage_cache.json")
 
 # entry schema: 1 = per-op decisions (choice/probe_ms/estimates_ms);
-# 2 adds joint pipeline decisions ("op": "attention", "stage_ms");
+# 2 adds joint pipeline decisions (an "op" and a "stage_ms" that new
+# entries no longer write; reads ignore both);
 # 3 adds bucket-level entries ("bucket": <bucket_sig>) written by the
 # batch scheduler; 4 adds per-entry running "stats" (fleet traffic +
 # observed-runtime EWMA + probe provenance) and the shared merge-on-

@@ -9,7 +9,6 @@ winner lands in the probed top-k; the guardrail absorbs estimate error.
 from __future__ import annotations
 
 import dataclasses
-import math
 from typing import Dict, Iterable
 
 from repro.core.features import (
@@ -142,64 +141,6 @@ def _row_serial_penalty(
     return weight * excess * step_t
 
 
-def _hub_anchors(feat: InputFeatures):
-    """(degree, fraction of rows above it) at the stored quantiles."""
-    return (
-        (max(feat.deg_p50, 1.0), 0.50),
-        (max(feat.deg_p90, 1.0), 0.10),
-        (max(feat.deg_p99, 1.0), 0.01),
-        (max(feat.deg_max, 1.0), 0.0),
-    )
-
-
-def hub_rows_bound(feat: InputFeatures, hub_t: float) -> float:
-    """Most rows of degree above ``hub_t``: at most the row fraction of
-    the highest quantile anchor ``hub_t`` clears (the deg_max anchor
-    left out, so one row always counts), and at most nnz / hub_t. The
-    registry's memory gates size hub-split gathers with it; the
-    estimate uses the interpolated ``_hub_row_frac`` instead."""
-    frac = min((f0 for d0, f0 in _hub_anchors(feat)[:3] if hub_t >= d0),
-               default=1.0)
-    return min(feat.n_rows * frac + 1, feat.nnz / max(hub_t, 1))
-
-
-def _hub_row_frac(feat: InputFeatures, hub_t: float) -> float:
-    """Fraction of rows whose degree exceeds ``hub_t``, reconstructed
-    from the stored degree quantiles by log-degree interpolation between
-    the anchors (p50, 0.50), (p90, 0.10), (p99, 0.01), (max, 0.0).
-
-    Replaces the old hard-coded 1% hub fraction, which mis-ranked
-    hub-split on any graph whose hub mass isn't exactly the top
-    percentile (a 10%-hub graph got its hub partition costed at a tenth
-    of its real size). Degenerate (equal) quantiles take the smaller
-    anchor fraction; below p50 clamps to 0.5 — past that the 'hub'
-    partition is most of the graph and the split is pointless anyway.
-    """
-    anchors = _hub_anchors(feat)
-    t = max(float(hub_t), 1.0)
-    if t < anchors[0][0]:
-        return 0.5
-    for (d0, f0), (d1, f1) in zip(anchors, anchors[1:]):
-        if d0 <= t < d1:
-            w = (math.log(t) - math.log(d0)) / (math.log(d1) - math.log(d0))
-            return f0 + (f1 - f0) * w
-        if d0 == d1 == t:
-            return min(f0, f1)
-    return 0.0  # t >= deg_max: no row exceeds it
-
-
-def _hub_light_width(feat: InputFeatures, frac: float) -> float:
-    """ELL width of the light partition: the largest degree quantile
-    that is still *below* the hub cut. The old model always used p99,
-    which for a many-hub graph is the hub degree itself — the light
-    partition (degree ~p50) got costed at hub width."""
-    if frac <= 0.01:
-        return feat.deg_p99
-    if frac <= 0.10:
-        return feat.deg_p90
-    return feat.deg_p50
-
-
 def estimate_spmm(feat: InputFeatures, hw: HardwareSpec, variant: str,
                   knobs: Dict) -> float:
     n, f, nnz = feat.n_rows, feat.f, feat.nnz
@@ -223,20 +164,6 @@ def estimate_spmm(feat: InputFeatures, hw: HardwareSpec, variant: str,
         flops = 2.0 * padded * f
         return _roofline(bytes_moved, flops, hw) + _row_serial_penalty(
             feat, hw, knobs
-        )
-    elif variant == "hub_split_ell":
-        hub_t = knobs.get("hub_threshold", feat.hub_threshold())
-        frac = _hub_row_frac(feat, hub_t)
-        light_pad = (feat.n_rows * (1.0 - frac)) * min(
-            _hub_light_width(feat, frac), hub_t
-        )
-        hub_pad = (feat.n_rows * frac + 1) * feat.deg_max
-        padded = light_pad + hub_pad
-        bytes_moved = padded * (f * BYTES_F32 + 8) + out_bytes * 1.2
-        flops = 2.0 * padded * f
-        # hub rows live in their own partition, so only half the tax
-        return _roofline(bytes_moved, flops, hw) + _row_serial_penalty(
-            feat, hw, knobs, weight=0.5
         )
     elif variant in ("block_ell_pallas", "ragged_ell_pallas", "hub_ragged_pallas"):
         ragged = variant != "block_ell_pallas"
@@ -344,7 +271,7 @@ _ATTN_STAGE_LAYOUT = {
 
 def estimate_attention(feat: InputFeatures, hw: HardwareSpec, variant: str,
                        knobs: Dict) -> float:
-    """Pipeline-granularity roofline for CSR attention (core/pipeline.py).
+    """Pipeline-granularity roofline for CSR attention (op="attention").
 
     Composed "pipe" candidates pay two inter-stage HBM round-trips that a
     per-op estimate never sees: SDDMM writes logits which softmax reads

@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.core.estimate import hub_rows_bound
 from repro.core.features import (
     HardwareSpec,
     InputFeatures,
@@ -92,20 +91,6 @@ _spmm_bucket_ell_jit = jax.jit(kx.spmm_bucket_ell, static_argnames="chunk_bytes"
 _sddmm_gather_jit = jax.jit(kx.sddmm_gather_dot)
 
 
-@functools.partial(jax.jit, static_argnums=0)
-def _spmm_hub_jit(n_rows: int, aux: Dict, b: jax.Array) -> jax.Array:
-    out = jnp.zeros((n_rows, b.shape[1]), jnp.float32)
-    if "hub_colind" in aux:
-        part = kx.spmm_row_ell({"colind": aux["hub_colind"], "val": aux["hub_val"]}, b)
-        out = out.at[aux["hub_rows"]].set(part)
-    if "light_colind" in aux:
-        part = kx.spmm_row_ell(
-            {"colind": aux["light_colind"], "val": aux["light_val"]}, b
-        )
-        out = out.at[aux["light_rows"]].set(part)
-    return out
-
-
 @jax.jit
 def _sddmm_ell_jit(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
     ell = kx.sddmm_row_ell(
@@ -117,9 +102,9 @@ def _sddmm_ell_jit(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
 def _gather_fits(rows_x_width: float, f: InputFeatures, hw: HardwareSpec) -> bool:
     """The XLA row-ELL kernels gather a (rows, width, F) f32 operand and
     XLA materializes it in HBM (a compile for a described v5e refused
-    row_ell and hub_split_ell on a 122k-node Products-shaped graph at
-    F=256 for 21.6 GB and 44.6 GB). A TPU lays the F columns out in 128
-    lanes, so F=47 takes 128 columns' bytes: row_ell's gather on that
+    row_ell on a 122k-node Products-shaped graph at F=256 for 21.6 GB).
+    A TPU lays the F columns out in 128 lanes, so F=47 takes 128
+    columns' bytes: row_ell's gather on that
     graph's transpose is 5.4 GB, and on a v5e it ran 91 ms a step where
     its probe on a subgraph had read it close to bucket_ell. A training
     step keeps a forward and a backward op's buffers plus activations
@@ -139,7 +124,7 @@ def _ell_applicable(f: InputFeatures, hw: HardwareSpec) -> bool:
 
 # ----------------------------------------------------------------- SpMM
 def _spmm_variants(feat: InputFeatures) -> List[Variant]:
-    vs = [
+    return [
         Variant(
             name="gather_segsum",
             op="spmm",
@@ -176,27 +161,6 @@ def _spmm_variants(feat: InputFeatures) -> List[Variant]:
             applicable=lambda f, hw: True,
         ),
     ]
-    hub_t = int(os.environ.get("AUTOSAGE_HUB_T", feat.hub_threshold()))
-    vs.append(
-        Variant(
-            name="hub_split_ell",
-            op="spmm",
-            prepare=lambda csr, t=hub_t: kx.prepare_hub_split_ell(csr, t),
-            build=lambda aux: (
-                lambda b, a=_dev(aux), n=int(aux["n_rows"]): _spmm_hub_jit(n, a, b)
-            ),
-            # heavy tail: a small set of rows dominates the work (the
-            # p99-based skew misses 1%-hub graphs like Table 10's)
-            applicable=lambda f, hw, t=hub_t: f.deg_max > 4 * max(f.avg_deg, 1.0)
-            and f.deg_max > 2 * max(f.deg_p50, 1.0)
-            # hub rows gather deg_max slots each, light rows at most t
-            and _gather_fits(
-                f.n_rows * t + hub_rows_bound(f, t) * f.deg_max, f, hw
-            ),
-            knobs={"hub_threshold": hub_t},
-        )
-    )
-    return vs
 
 
 @functools.partial(jax.jit, static_argnums=(0, 1, 2))
@@ -918,9 +882,9 @@ def _pallas_sddmm_variants(feat: InputFeatures, interpret: bool) -> List[Variant
 # ------------------------------------------ attention (whole pipelines)
 # Composed SDDMM -> row-softmax -> SpMM candidates, one Variant per
 # {sddmm layout x spmm layout} pair, plus the fused flash-style Pallas
-# kernel. The pipeline scheduler (core/pipeline.py) probes these
-# end-to-end; a per-op decide can never justify the fused kernel because
-# its benefit (no logits/probs HBM round-trip) lies *between* ops.
+# kernel. An op="attention" decide probes these end to end; a decide
+# per op can never justify the fused kernel, because its benefit (no
+# logits/probs HBM round-trip) lies *between* ops.
 
 _attn_csr_jit = jax.jit(kx.attention_csr)
 _attn_ell_jit = jax.jit(kx.attention_ell)

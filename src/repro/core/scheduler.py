@@ -215,9 +215,8 @@ class AutoSage:
         performance (a failure mode of the paper's single-point probe we
         hit on ER; see EXPERIMENTS.md "probe-scale bias").
         AUTOSAGE_PROBE_MODE=point restores the paper's single-point
-        behaviour. Shared by the per-op `decide` and the pipeline-level
-        attention scheduler (core/pipeline.py), so composed candidates
-        are probed end-to-end under the exact same protocol.
+        behaviour. An "attention" decide probes whole pipelines, so
+        composed candidates are timed end to end under this protocol.
         """
         mode = os.environ.get("AUTOSAGE_PROBE_MODE", "slope")
         t_probe0 = time.perf_counter()
@@ -338,7 +337,9 @@ class AutoSage:
         probe_args_fn(sub_csr) -> dense args for one probe invocation;
         defaults to random dense operands of width F. ``heads`` > 1
         decides one op over (n, heads, F) operands, keyed by its head
-        count too.
+        count too. ``op="attention"`` decides whole SDDMM -> softmax ->
+        SpMM pipelines (the composed candidates and the fused kernel of
+        core/registry.py), F being the head width.
 
         On an exact-key miss, a peer device class's probed entry for the
         SAME graph can short-circuit the probe (estimate-space transfer,
@@ -616,25 +617,10 @@ class AutoSage:
         d = self.decide(csr, int(x.shape[1]), "sddmm", seed=seed)
         return self.build_runner(csr, d)(x, y), d
 
-    # ---- pipeline-level CSR attention (core/pipeline.py) -------------
-    def decide_attention(
-        self, csr: CSR, d: int, seed: int = 0, stage_breakdown: bool = False,
-        allow_transfer: bool = True, heads: int = 1,
-    ):
-        """Joint decision over composed {sddmm x softmax x spmm} pipelines
-        and the fused Pallas kernel; cached under op="attention"."""
-        from repro.core import pipeline
-
-        return pipeline.decide_attention(
-            self, csr, d, seed=seed, stage_breakdown=stage_breakdown,
-            allow_transfer=allow_transfer, heads=heads,
-        )
-
     def attention(self, csr: CSR, q, k, v, seed: int = 0):
         """Deprecated; use `repro.api.attention(csr, q, k, v, sage=...)`."""
         _warn_deprecated(
             "AutoSage.attention", "repro.api.attention(csr, q, k, v, sage=...)"
         )
-        from repro.core import pipeline
-
-        return pipeline.attention_forward(self, csr, q, k, v, seed=seed)
+        d = self.decide(csr, int(q.shape[-1]), "attention", seed=seed)
+        return self.build_runner(csr, d)(q, k, v), d

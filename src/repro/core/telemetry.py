@@ -215,27 +215,3 @@ def emit_decide_event(
     append_jsonl(path, rec)
     return path
 
-
-def emit_attention_decision(decision) -> Optional[str]:
-    """Per-stage breakdown stream for pipeline decisions (§8.7 analysis).
-
-    No-op unless AUTOSAGE_TELEMETRY_DIR is set, so the scheduler hot path
-    never touches the filesystem by default. Returns the path written.
-    """
-    out = os.environ.get("AUTOSAGE_TELEMETRY_DIR")
-    if not out:
-        return None
-    path = str(Path(out) / "attention_decisions.jsonl")
-    append_jsonl(
-        path,
-        {
-            "op": decision.op,
-            "choice": decision.choice,
-            "from_cache": decision.from_cache,
-            "probe_ms": decision.probe_ms,
-            "stage_ms": getattr(decision, "stage_ms", {}),
-            "estimates_ms": decision.estimates_ms,
-            "probe_overhead_ms": decision.probe_overhead_ms,
-        },
-    )
-    return path

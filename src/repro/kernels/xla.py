@@ -332,41 +332,6 @@ def spmm_bucket_ell(aux: Dict, b: jax.Array,
     return jnp.concatenate(parts)[aux["inv"]]
 
 
-def prepare_hub_split_ell(csr: CSR, hub_threshold: int) -> Dict[str, np.ndarray]:
-    """Two ELL partitions split by degree (CTA-per-hub analogue)."""
-    from repro.sparse.bsr import hub_split
-
-    hub_rows, light_rows = hub_split(csr, hub_threshold)
-    aux: Dict[str, np.ndarray] = {
-        "hub_rows": hub_rows.astype(np.int32),
-        "light_rows": light_rows.astype(np.int32),
-        "n_rows": np.int32(csr.n_rows),
-    }
-    if hub_rows.size:
-        sub = csr.row_slice(hub_rows)
-        h = prepare_row_ell(sub)
-        aux["hub_colind"], aux["hub_val"] = h["colind"], h["val"]
-    if light_rows.size:
-        sub = csr.row_slice(light_rows)
-        l = prepare_row_ell(sub)
-        aux["light_colind"], aux["light_val"] = l["colind"], l["val"]
-    return aux
-
-
-def spmm_hub_split_ell(aux: Dict, b: jax.Array) -> jax.Array:
-    n = int(aux["n_rows"])
-    out = jnp.zeros((n, b.shape[1]), jnp.float32)
-    if "hub_colind" in aux:
-        part = spmm_row_ell({"colind": aux["hub_colind"], "val": aux["hub_val"]}, b)
-        out = out.at[aux["hub_rows"]].set(part)
-    if "light_colind" in aux:
-        part = spmm_row_ell(
-            {"colind": aux["light_colind"], "val": aux["light_val"]}, b
-        )
-        out = out.at[aux["light_rows"]].set(part)
-    return out
-
-
 # --------------------------------------------------------------- SDDMM
 def sddmm_gather_dot(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
     """Paper's SDDMM baseline: gather both sides, dot."""
@@ -386,7 +351,7 @@ def sddmm_row_ell(aux: Dict, x: jax.Array, y: jax.Array) -> jax.Array:
 
 
 # ------------------------------------------- composed attention pipelines
-# The pipeline scheduler (core/pipeline.py) selects among these whole
+# An op="attention" decide selects among these whole
 # SDDMM -> row-softmax -> SpMM compositions; each stays in one sparse
 # layout per stage, with explicit layout conversion for mixed pairs.
 

@@ -1,6 +1,7 @@
-"""Pipeline-level CSR attention scheduling (core/pipeline.py): composed
-vs fused numerical agreement, joint-decision caching, replay-only mode,
-estimate/registry wiring."""
+"""Pipeline-level CSR attention scheduling through `AutoSage.decide`:
+composed vs fused numerical agreement, joint-decision caching,
+replay-only mode, estimate/registry wiring, and the per-stage breakdown
+diagnostic (core/pipeline.py)."""
 import numpy as np
 import pytest
 
@@ -12,9 +13,11 @@ from repro.core import (
     InputFeatures,
     ReplayMiss,
     ScheduleCache,
+    device_sig,
 )
 from repro.core import estimate as est
-from repro.core import registry
+from repro.core import obs, registry
+from repro.core.pipeline import probe_stage_breakdown
 from repro.kernels import ref
 from repro.sparse import hub_skew
 
@@ -128,7 +131,7 @@ def test_attention_cache_hit_and_replay(tmp_path):
     assert d2.from_cache and d2.choice == d1.choice
     # fresh process-like state replays the joint decision from disk
     sage_r = AutoSage(cache=ScheduleCache(path=path, replay_only=True))
-    d3 = sage_r.decide_attention(csr, 16)
+    d3 = sage_r.decide(csr, 16, "attention")
     assert d3.from_cache and d3.choice == d1.choice
     # the attention entry is keyed under its own op
     assert any("|attention|" in k2 for k2 in sage.cache.keys_for_op("attention"))
@@ -143,15 +146,36 @@ def test_attention_replay_miss_env(tmp_path, monkeypatch):
         probe_frac=0.3,
     )
     csr = _skewed_csr(n=1000, seed=5)
-    sage.decide_attention(csr, 16)
+    sage.decide(csr, 16, "attention")
     # replay-only via the env contract: cached key replays, unseen raises
     monkeypatch.setenv("AUTOSAGE_REPLAY_ONLY", "1")
     sage_r = AutoSage(cache=ScheduleCache(path=path))
     assert sage_r.cache.replay_only
-    assert sage_r.decide_attention(csr, 16).from_cache
+    assert sage_r.decide(csr, 16, "attention").from_cache
     other = _skewed_csr(n=999, seed=6)
     with pytest.raises(ReplayMiss):
-        sage_r.decide_attention(other, 16)
+        sage_r.decide(other, 16, "attention")
+
+
+def test_attention_entry_with_op_and_stage_ms_replays(tmp_path, monkeypatch):
+    """Entries written before attention shared the decide body carry an
+    "op" and a "stage_ms" that no path writes now: under
+    AUTOSAGE_REPLAY_ONLY=1 they still replay their pinned choice."""
+    csr = _skewed_csr(n=1000, seed=5)
+    feat = InputFeatures.from_csr(csr, 16, "attention")
+    choice = next(v.full_name() for v in registry.candidates(feat, HardwareSpec.cpu())
+                  if not v.is_baseline)
+    path = str(tmp_path / "cache.json")
+    ScheduleCache(path=path).put(
+        ScheduleCache.key(device_sig(), feat.graph_sig, 16, "attention", 0.95),
+        {"choice": choice, "probe_ms": {"baseline": 2.0, choice: 1.0},
+         "estimates_ms": {}, "op": "attention",
+         "stage_ms": {"sddmm": 0.3, "softmax": 0.4, "spmm": 0.3}},
+    )
+    monkeypatch.setenv("AUTOSAGE_REPLAY_ONLY", "1")
+    d = AutoSage(cache=ScheduleCache(path=path), alpha=0.95,
+                 hw=HardwareSpec.cpu()).decide(csr, 16, "attention")
+    assert d.from_cache and d.choice == choice == d.variant.full_name()
 
 
 def test_attention_stage_breakdown():
@@ -160,12 +184,30 @@ def test_attention_stage_breakdown():
         cache=ScheduleCache(path=None), probe_iters=1, probe_cap_ms=100,
         probe_frac=0.3,
     )
-    d = sage.decide_attention(csr, 16, stage_breakdown=True)
-    assert set(d.stage_ms) == {"sddmm", "softmax", "spmm"} or set(d.stage_ms) == {"fused"}
-    assert all(ms >= 0 for ms in d.stage_ms.values())
-    # breakdown round-trips through the cache entry
-    d2 = sage.decide_attention(csr, 16)
-    assert d2.from_cache and d2.stage_ms == d.stage_ms
+    d = sage.decide(csr, 16, "attention")
+    stage_ms = probe_stage_breakdown(sage, csr, 16, d.variant)
+    assert set(stage_ms) == {"sddmm", "softmax", "spmm"} or set(stage_ms) == {"fused"}
+    assert all(ms >= 0 for ms in stage_ms.values())
+
+
+def test_attention_decide_counts_choice_and_syncs_breaker(monkeypatch):
+    """The model path's attention decide is accounted like every other
+    op's: it counts its choice, and its cold path folds in peers'
+    quarantines before shortlisting."""
+    from repro import api
+
+    csr = _skewed_csr(n=400, seed=8)
+    q, k, v = _qkv(csr, 16)
+    sage = AutoSage(cache=ScheduleCache(path=None), top_k=0)
+    syncs = []
+    monkeypatch.setattr(sage.breaker, "maybe_sync", lambda: syncs.append(1))
+    before = obs.REGISTRY.total(
+        "autosage_decide_choice_total", op="attention", choice="baseline")
+    api.attention(csr, q, k, v, sage=sage, differentiable=False)
+    assert syncs
+    assert obs.REGISTRY.total(
+        "autosage_decide_choice_total", op="attention", choice="baseline"
+    ) == before + 1
 
 
 def test_pipeline_estimate_charges_roundtrips():

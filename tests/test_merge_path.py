@@ -2,7 +2,7 @@
 bit-identity with the ragged kernels and the CSR oracles on hub-dominated
 extremes (fwd + dynamic-vals bwd), the roofline's row-serialization
 penalty that ranks merge-path first under skew without a probe, and the
-satellite bugfixes that rode along (hub-fraction quantiles, padding-waste
+satellite bugfixes that rode along (many-hub ranking, padding-waste
 fallback telemetry, int32 layout guards, balance bucketing)."""
 import dataclasses
 
@@ -13,8 +13,6 @@ from repro.core import registry
 from repro.core import estimate as est_mod
 from repro.core.estimate import (
     _block_ell_elems,
-    _hub_light_width,
-    _hub_row_frac,
     _row_serial_penalty,
     estimate,
 )
@@ -194,42 +192,17 @@ def test_estimate_keeps_ragged_first_when_balanced():
     assert t_ragged <= t_merge
 
 
-# ------------------------------------------- satellite: hub fraction
-def test_hub_row_frac_tracks_actual_hub_mass():
-    """Regression for the hard-coded 1% hub fraction: a 10%-hub graph's
-    hub partition must be costed near its real size, not a tenth of it."""
-    csr = hub_skew(2000, 4, 0.10, 1000, seed=1)
-    feat = InputFeatures.from_csr(csr, 64, "spmm")
-    deg = np.diff(csr.rowptr)
-    for hub_t in (int(feat.deg_p90), 150, 400):
-        actual = float((deg > hub_t).mean())  # 0.10: the hub block
-        modeled = _hub_row_frac(feat, hub_t)
-        # within 3x of truth and nowhere near the old fixed 1%
-        assert modeled >= max(actual / 3.0, 0.02), (hub_t, actual, modeled)
-        assert modeled <= max(3.0 * actual, 0.5), (hub_t, actual, modeled)
-    # boundary behaviour: a cut at/above deg_max means no hub rows at
-    # all (this graph's p99 == deg_max, so hub_threshold() lands there)
-    assert _hub_row_frac(feat, feat.deg_max) == 0.0
-    assert _hub_row_frac(feat, 1.0) == 0.5
-    # light-partition width follows the hub cut down the quantile ladder
-    assert _hub_light_width(feat, 0.005) == feat.deg_p99
-    assert _hub_light_width(feat, 0.05) == feat.deg_p90
-    assert _hub_light_width(feat, 0.3) == feat.deg_p50
-
-
+# ------------------------------------------- satellite: many-hub graph
 def test_hub_split_estimate_improves_on_many_hub_graph():
-    """With the quantile-derived fraction, hub_split's estimate on a
-    10%-hub graph must beat plain row_ell at a cut that actually peels
-    the hub block (the old 1% model undercosted the hub partition by 10x
-    AND costed the light partition at hub width, so the ordering was
-    fragile)."""
+    """On a 10%-hub graph bucket_ell's estimate must beat plain row_ell:
+    its degree buckets pad the hub block and the light rows to their own
+    widths, where row_ell pads every row to the hub degree."""
     hw = HardwareSpec.tpu_v5e()
     csr = hub_skew(2000, 4, 0.10, 1000, seed=1)
     feat = InputFeatures.from_csr(csr, 64, "spmm")
-    t_split = estimate(feat, hw, "hub_split_ell",
-                       {"hub_threshold": int(feat.deg_p90)})
+    t_bucket = estimate(feat, hw, "bucket_ell", {})
     t_row = estimate(feat, hw, "row_ell", {})
-    assert t_split < t_row, (t_split, t_row)
+    assert t_bucket < t_row, (t_bucket, t_row)
 
 
 # ----------------------------------- satellite: padding-waste fallback

@@ -150,7 +150,7 @@ def test_registry_applicability_gates():
     names = {v.name for v in registry.candidates(feat, hw, include_pallas=False)}
     assert "dense" not in names        # 4e10 dense elements: gated out
     assert "row_ell" not in names      # deg_max >> avg: padding explosion
-    assert "hub_split_ell" in names    # skewed tail: the hub split applies
+    assert "bucket_ell" in names       # skewed tail: degree buckets apply
     assert "gather_segsum" in names
 
 

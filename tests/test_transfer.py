@@ -61,7 +61,7 @@ def _entry(ranking, choice, probed_at=100.0):
 def _names():
     base = _FakeVariant("gather_segsum")
     a = _FakeVariant("row_ell")
-    b = _FakeVariant("hub_split_ell", {"hub_threshold": 24})
+    b = _FakeVariant("bucket_ell")
     by_name = {a.full_name(): a, b.full_name(): b}
     return base, a, b, by_name
 

@@ -230,10 +230,8 @@ def test_heads_are_part_of_the_cache_key():
     csr = GRAPHS["power_law"]()
     sage = AutoSage(cache=ScheduleCache(path=None), top_k=0)
     for op in ("attention", "attention_bwd_v"):
-        decide = sage.decide_attention if op == "attention" else functools.partial(
-            sage.decide, op=op)
-        decide(csr, 32, heads=4)
-        decide(csr, 128, heads=1)
+        sage.decide(csr, 32, op, heads=4)
+        sage.decide(csr, 128, op, heads=1)
         keys = sage.cache.keys_for_op(op)
         assert sorted((parse_key(k).heads, parse_key(k).f) for k in keys) == [(1, 128), (4, 32)]
         assert "|H=4|" in next(k for k in keys if parse_key(k).heads == 4)
